@@ -9,9 +9,12 @@ metric — measured by the traversal engine for the tree path, analytic
 in every phase rotation) plus wall clock for both, and emit
 ``BENCH_distributed.json``.
 
-Multi-device CPU execution needs ``XLA_FLAGS`` set before jax import, so
-``run()`` re-executes this module in a subprocess with 8 forced host
-devices; ``python -m benchmarks.bench_distributed`` does the same.
+On CPU, multi-device execution needs ``XLA_FLAGS`` set before jax
+import, so ``run()`` re-executes this module in a subprocess with 8
+forced host devices; ``python -m benchmarks.bench_distributed`` does the
+same. On an accelerator the measurement runs in-process on the real
+devices: a chip belongs to the process that first touched it, so a child
+could not reach it.
 """
 from __future__ import annotations
 
@@ -91,7 +94,7 @@ def _inner(sizes, json_out):
     return records
 
 
-def _evals_only(sizes):
+def _evals_only(sizes) -> dict:
     """Deterministic work counters for the sharded tree path (no timing,
     no ring comparator) — the ``run.py --check`` regression gate."""
     from repro.data import pointclouds
@@ -102,21 +105,35 @@ def _evals_only(sizes):
         _, st = tree_dbscan_sharded(pts, EPS, MINPTS, with_stats=True)
         out[f"n{n}"] = {"tree_distance_evals": st["distance_evals"],
                         "tree_sweeps": st["n_sweeps"]}
-    print("EVALS_JSON=" + json.dumps(out))
+    return out
 
 
-def measure_evals(sizes) -> dict:
-    """Run :func:`_evals_only` under 8 forced host devices; parsed dict."""
+def _on_cpu() -> bool:
+    """Is this process's backend the CPU? (Initializes it: on a chip the
+    caller then holds the chip, which is why it must measure in-process.)"""
+    import jax
+    return jax.default_backend() == "cpu"
+
+
+def _spawn(args) -> subprocess.CompletedProcess:
+    """Re-run this module under 8 forced host devices (CPU only)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count="
                          f"{N_DEVICES}",
                PYTHONPATH=os.path.join(repo, "src")
                + os.pathsep + os.environ.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.bench_distributed",
-           "--evals-only", "--sizes", ",".join(str(n) for n in sizes)]
-    r = subprocess.run(cmd, env=env, cwd=repo, text=True,
-                       capture_output=True)
+    return subprocess.run([sys.executable, "-m",
+                           "benchmarks.bench_distributed", *args],
+                          env=env, cwd=repo, text=True, capture_output=True)
+
+
+def measure_evals(sizes) -> dict:
+    """:func:`_evals_only` — under 8 forced host devices on CPU, on the
+    real devices otherwise."""
+    if not _on_cpu():
+        return _evals_only(sizes)
+    r = _spawn(["--evals-only", "--sizes", ",".join(str(n) for n in sizes)])
     if r.returncode != 0:
         sys.stderr.write(r.stderr)
         raise RuntimeError("bench_distributed evals-only run failed")
@@ -128,21 +145,16 @@ def measure_evals(sizes) -> dict:
 
 def run(sizes=(4096, 16384), quick: bool = False,
         json_out: str = "BENCH_distributed.json"):
-    """Spawn the measurement under 8 forced host devices and relay output."""
+    """Measure under 8 forced host devices (CPU: a relayed subprocess) or
+    on the real devices (accelerator: in-process)."""
     if quick:
         sizes = tuple(n for n in sizes if n <= 16384)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count="
-                         f"{N_DEVICES}",
-               PYTHONPATH=os.path.join(repo, "src")
-               + os.pathsep + os.environ.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.bench_distributed", "--inner",
-           "--sizes", ",".join(str(n) for n in sizes)]
+    if not _on_cpu():
+        return _inner(sizes, json_out)
+    args = ["--inner", "--sizes", ",".join(str(n) for n in sizes)]
     if json_out:
-        cmd += ["--json", json_out]
-    r = subprocess.run(cmd, env=env, cwd=repo, text=True,
-                       capture_output=True)
+        args += ["--json", json_out]
+    r = _spawn(args)
     sys.stdout.write(r.stdout)
     if r.returncode != 0:
         sys.stderr.write(r.stderr)
@@ -158,7 +170,7 @@ def main() -> None:
     args = ap.parse_args()
     sizes = tuple(int(s) for s in args.sizes.split(","))
     if args.evals_only:
-        _evals_only(sizes)
+        print("EVALS_JSON=" + json.dumps(_evals_only(sizes)))
     elif args.inner:
         _inner(sizes, args.json)
     else:

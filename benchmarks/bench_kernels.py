@@ -19,8 +19,8 @@ def run(quick: bool = False):
     for n in ([1024] if quick else [1024, 4096]):
         pts = pointclouds.load("portotaxi_like", n)
         eps = 0.01
-        # MXU form: n^2 x (2d for dot + 5 elementwise) flops
-        flops = n * n * (2 * 2 + 5)
+        # difference form: n^2 x (d subs + d muls + d-1 adds + 1 compare)
+        flops = n * n * (3 * 2)
         tile_bytes = 128 * 2 * 4 + 128 * 2 * 4 + 128 * 128 * 4
         dt, _ = time_fn(pairwise_count, pts, pts, eps,
                         warmup=1, repeat=1 if quick else 3)

@@ -237,6 +237,8 @@ def main() -> None:
                          "phase,kernels,dist_evals,distributed,stream,"
                          "serve")
     args = ap.parse_args()
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     if args.check:
         check()
         return

@@ -44,12 +44,11 @@ def _doc(obj) -> str:
     return inspect.getdoc(obj) or "*(no docstring)*"
 
 
-def _entry(title: str, obj, kind: str = "function",
-           sig: str | None = None) -> str:
+def _entry(title: str, obj, kind: str = "function") -> str:
     lines = [f"### `{title}`", ""]
     if kind == "function":
         name = title.rsplit(".", 1)[-1]
-        lines += ["```python", f"{name}{sig or _signature(obj)}", "```", ""]
+        lines += ["```python", f"{name}{_signature(obj)}", "```", ""]
     lines += [_doc(obj), ""]
     return "\n".join(lines)
 
@@ -192,14 +191,8 @@ def generate() -> str:
         parts.append(_entry(f"traversal.{cls.__name__}", cls, kind="class"))
 
     parts.append("## Traversal engines\n")
-    # DEFAULT_UNROLL resolves per backend (4 on TPU/GPU, 1 on CPU);
-    # render the symbol so the generated file is machine-independent
-    # (annotations render quoted under `from __future__ import annotations`)
-    engine_sig = _signature(traversal.traverse_impl).replace(
-        f"unroll: 'int' = {traversal.DEFAULT_UNROLL}",
-        "unroll: 'int' = DEFAULT_UNROLL")
     parts.append(_entry("repro.core.traversal.traverse",
-                        traversal.traverse_impl, sig=engine_sig))
+                        traversal.traverse_impl))
     parts.append(_entry("repro.kernels.traverse.traverse",
                         pallas_traverse.traverse))
     parts.append(_entry("traversal.Trace", traversal.Trace, kind="class"))

@@ -6,6 +6,7 @@ Everything here uses only the package root's exports (``repro.dbscan``,
 ``repro.plan``, ``repro.stream_handle``, ``repro.neighbors``,
 ``repro.DBSCANResult``) — the API contract DESIGN.md §8.1 documents.
 """
+import jax
 import numpy as np
 
 import repro
@@ -19,7 +20,12 @@ def main():
     pts = pointclouds.blobs(2000, k=6, seed=42)
     eps, min_pts = 0.04, 8
 
-    for algo in ("fdbscan", "fdbscan-densebox", "tiled", "pallas-tree"):
+    algos = ["fdbscan", "fdbscan-densebox", "tiled"]
+    if jax.default_backend() != "tpu":
+        # the Pallas traversal kernel runs interpreted off TPU only; on a
+        # TPU Mosaic refuses it (repro.kernels.traverse.TPU_UNSUPPORTED)
+        algos.append("pallas-tree")
+    for algo in algos:
         res = repro.dbscan(pts, eps, min_pts, algorithm=algo)
         assert isinstance(res, repro.DBSCANResult)
         noise = int((np.asarray(res.labels) == -1).sum())

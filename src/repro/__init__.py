@@ -9,7 +9,7 @@ behind one auto-dispatching entry point. See README.md and docs/api.md.
 Stable public surface — everything an application needs lives here:
 
   * :func:`dbscan`        — clustering with automatic backend selection
-                            (tree walk, MXU tiles, sharded multi-device,
+                            (tree walk, Pallas tiles, sharded multi-device,
                             or a one-shot streaming snapshot);
   * :func:`plan`          — backend decision + cached index build, for
                             amortizing eps/min_pts parameter sweeps;

@@ -1,7 +1,7 @@
 """Core library: the paper's tree-based DBSCAN algorithms on TPU/JAX.
 
 ``dbscan`` is the unified auto-dispatching entry point (DESIGN.md §5): it
-plans a backend (tree walk or MXU tiles) per input and reuses cached
+plans a backend (tree walk or Pallas tiles) per input and reuses cached
 indexes across eps/min_pts sweeps. The per-algorithm implementations stay
 importable via ``fdbscan`` and ``kernels.ops``.
 """

@@ -8,7 +8,7 @@
   level-synchronous BFS. We reproduce it with a dense adjacency matrix, so
   its memory is Theta(n^2) bits regardless of eps — the memory benchmark
   (benchmarks/bench_memory.py) contrasts this against FDBSCAN's O(n).
-* ``dbscan_tiled`` lives in repro.kernels.ops — the MXU tile backend.
+* ``dbscan_tiled`` lives in repro.kernels.ops — the Pallas tile backend.
 """
 from __future__ import annotations
 

@@ -11,16 +11,16 @@ serving the backends:
                            build doubles as the density probe that drives
                            the auto heuristic, so choosing this backend
                            costs no extra work.
-  * ``tiled``            — the MXU Pallas tile backend (kernels/ops.py):
+  * ``tiled``            — the Pallas tile backend (kernels/ops.py):
                            n^2 streamed distance tiles beat a divergent
                            tree walk when the point count is small.
   * ``pallas-tree``      — the same tree algorithms with every traversal
                            run as the lane-tiled Pallas kernel
-                           (kernels/traverse.py; DESIGN.md §9). Auto
-                           dispatch upgrades any tree decision to this
-                           backend on TPU (where the DBSCAN visitors are
-                           kernel-fusible and the index fits VMEM);
-                           bit-identical labels.
+                           (kernels/traverse.py; DESIGN.md §9);
+                           bit-identical labels. Explicit only, and off
+                           TPU only: Mosaic refuses the kernel's
+                           per-lane index gathers, so a TPU request
+                           raises instead of running the emulator.
   * ``sharded``          — the multi-device tree path (DESIGN.md §6):
                            shard-local LBVH traversal + eps-halo exchange
                            (distributed/ring_dbscan.tree_dbscan_sharded).
@@ -52,7 +52,7 @@ from . import fdbscan, grid, lbvh, tune
 from .validate import check_points
 
 # Below this size the n^2 tile sweep is cheaper than divergent traversal
-# (one 128x128 MXU tile row per query block), and it keeps the CPU
+# (one 128x128 tile row per query block), and it keeps the CPU
 # interpret-mode path exercised in tests.
 TILED_MAX_POINTS = 1024
 # Minimum fraction of points inside dense cells for the DenseBox index to
@@ -66,52 +66,10 @@ ALGORITHMS = ("auto", "fdbscan", "fdbscan-densebox", "tiled", "sharded",
               "stream", "pallas-tree")
 
 
-# The traversal kernel keeps the whole index (points, boxes, ropes,
-# segment tables) VMEM-resident; past roughly half a core's ~16 MB the
-# upgrade would trade a working vmapped walk for a compile failure, so
-# auto dispatch stays on the reference engine beyond this footprint.
-# Explicit algorithm="pallas-tree" bypasses the guard (the caller asked).
-PALLAS_MAX_INDEX_BYTES = 8 << 20
-
-
 def _accel() -> bool:
-    """Does jit target the TPU Pallas lowering? (the pallas-tree auto
-    heuristic; split out so tests can pin it). GPU is deliberately
-    excluded: the kernel's TPU compiler params make Pallas fall back to
-    interpret mode there, which would silently replace the fast vmapped
-    engine with an emulated kernel."""
+    """Does jit target the TPU? (split out so tests can pin it)."""
     import jax
     return jax.default_backend() == "tpu"
-
-
-def _index_vmem_bytes(p: "Plan") -> int | None:
-    """Rough whole-index footprint the kernel pins in VMEM (int32/float32
-    arrays: points, per-point ids, segment tables, node boxes + links)."""
-    if p.segs is None or p.tree is None:
-        return None
-    n, d = p.segs.pts.shape
-    m = p.segs.seg_start.shape[0]
-    n_nodes = p.tree.miss.shape[0]
-    return 4 * (n * d + n + 3 * m + 2 * n_nodes * d + 4 * n_nodes)
-
-
-def _maybe_pallas(p: "Plan", algorithm: str, eps: float,
-                  min_pts: int) -> "Plan":
-    """Upgrade an auto tree decision to the Pallas kernel engine on TPU.
-    The kernel runs the same index with the same visitor callbacks
-    (always fusible for the DBSCAN epilogues), so the upgrade changes
-    only the walk's lowering — labels stay bit-identical. Skipped when
-    the index would overflow the kernel's VMEM residency budget."""
-    if algorithm != "auto" or not _accel():
-        return p
-    footprint = _index_vmem_bytes(p)
-    if footprint is None or footprint > PALLAS_MAX_INDEX_BYTES:
-        return p
-    stats = dict(p.stats)
-    stats["reason"] = (stats.get("reason", "") +
-                       "; accelerator: pallas traversal kernel")
-    return _attach_tune(p._replace(backend="pallas-tree", stats=stats),
-                        eps, min_pts)
 
 
 def _attach_tune(p: "Plan", eps: float, min_pts: int) -> "Plan":
@@ -244,9 +202,7 @@ def plan(points, eps: float, min_pts: int,
     same segments become the index (no duplicated work). An active ``mesh``
     routes to the sharded multi-device tree path (whose per-shard index is
     built inside the collective program — nothing to cache here beyond the
-    decision). On TPU an auto tree decision upgrades to the
-    ``pallas-tree`` kernel engine when the index fits its VMEM
-    residency budget (DESIGN.md §9).
+    decision).
 
     Args:
         points: (n, d) point array (any array-like; converted to jnp).
@@ -268,6 +224,9 @@ def plan(points, eps: float, min_pts: int,
             :func:`repro.core.validate.check_points`); ``mesh=`` combined
             with a single-device algorithm; a sharded request whose mesh
             lacks ``axis``; or a stream request with d ∉ {2, 3}.
+        NotImplementedError: ``algorithm="pallas-tree"`` on a TPU (the
+            kernel has no compiled TPU form; see
+            ``repro.kernels.traverse.TPU_UNSUPPORTED``).
     """
     with obs_trace.span("plan", algorithm=algorithm) as sp:
         p = _plan_impl(points, eps, min_pts, algorithm, mesh, axis)
@@ -328,12 +287,15 @@ def _plan_impl(points, eps: float, min_pts: int, algorithm: str,
                               backend="stream"))
     if algorithm == "tiled" or (algorithm == "auto" and n <= TILED_MAX_POINTS):
         stats["reason"] = ("explicit" if algorithm == "tiled"
-                           else f"n <= {TILED_MAX_POINTS}: MXU tiles win")
+                           else f"n <= {TILED_MAX_POINTS}: Pallas tiles win")
         return _cache_put(key, Plan("tiled", None, None, stats))
 
     if algorithm == "pallas-tree":
         # the Pallas traversal kernel over the plain (eps-independent,
-        # cached) fdbscan index — the explicit form of the auto upgrade
+        # cached) fdbscan index
+        if _accel():
+            from repro.kernels.traverse import TPU_UNSUPPORTED
+            raise NotImplementedError(TPU_UNSUPPORTED)
         stats["reason"] = "explicit: Pallas traversal kernel"
         return _cache_put(key, _attach_tune(
             _fdbscan_plan(points, pkey, stats)._replace(
@@ -342,8 +304,7 @@ def _plan_impl(points, eps: float, min_pts: int, algorithm: str,
     if algorithm == "fdbscan" or d not in (2, 3):
         stats["reason"] = ("explicit" if algorithm == "fdbscan"
                            else "no eps-grid for this dimensionality")
-        return _cache_put(key, _maybe_pallas(
-            _fdbscan_plan(points, pkey, stats), algorithm, eps, min_pts))
+        return _cache_put(key, _fdbscan_plan(points, pkey, stats))
 
     # eps-grid build: density probe and (potentially) the index itself
     with obs_trace.span("build", index="densebox") as sp:
@@ -355,12 +316,10 @@ def _plan_impl(points, eps: float, min_pts: int, algorithm: str,
     if algorithm == "fdbscan-densebox" or dense_frac >= DENSE_FRACTION_MIN:
         stats["reason"] = ("explicit" if algorithm == "fdbscan-densebox"
                            else f"dense_fraction >= {DENSE_FRACTION_MIN}")
-        return _cache_put(key, _maybe_pallas(
-            Plan("fdbscan-densebox", segs, _tree_of(segs), stats),
-            algorithm, eps, min_pts))
+        return _cache_put(key, Plan("fdbscan-densebox", segs,
+                                    _tree_of(segs), stats))
     stats["reason"] = f"dense_fraction < {DENSE_FRACTION_MIN}: plain tree"
-    return _cache_put(key, _maybe_pallas(
-        _fdbscan_plan(points, pkey, stats), algorithm, eps, min_pts))
+    return _cache_put(key, _fdbscan_plan(points, pkey, stats))
 
 
 def dbscan(points, eps: float, min_pts: int, *, algorithm: str = "auto",
@@ -440,12 +399,8 @@ def _execute(p: Plan, points, eps: float, min_pts: int, *, star: bool,
         h = StreamingDBSCAN(points, eps, min_pts, index=(p.segs, p.tree))
         return h.snapshot(star=star)
     if p.backend == "tiled":
-        import jax
         from repro.kernels import ops
-        # interpret mode only off-TPU (the Pallas kernels are TPU-tiled;
-        # interpret=True is the CPU-test emulation path)
-        return ops.dbscan_tiled(points, eps, min_pts, star=star,
-                                interpret=jax.default_backend() != "tpu")
+        return ops.dbscan_tiled(points, eps, min_pts, star=star)
     if p.tune is not None:
         # Record the decision in the metrics snapshot (DESIGN.md §12):
         # an info-style gauge whose labels carry the per-phase choice.

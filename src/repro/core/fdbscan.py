@@ -531,7 +531,7 @@ def dbscan(points, eps: float, min_pts: int, *, algorithm: str = "auto",
     algorithm: "fdbscan" | "fdbscan-densebox" build the named tree index
     directly; "auto", "tiled", "sharded", "stream" and "pallas-tree" go
     through the unified dispatcher (repro.core.dispatch), which probes the
-    eps-grid occupancy and may pick the MXU tile backend, the multi-device
+    eps-grid occupancy and may pick the Pallas tile backend, the multi-device
     sharded tree path (when a ``mesh`` is active), the Pallas traversal
     kernel (DESIGN.md §9), or a one-shot streaming snapshot (DESIGN.md §7;
     use ``dispatch.stream_handle`` to keep the handle for inserts).

@@ -75,12 +75,17 @@ from .grid import Segments
 
 INT_MAX = jnp.iinfo(jnp.int32).max
 
-# Work units per while_loop trip. On lockstep accelerators (TPU/GPU) 4
-# amortizes the loop-carried overhead (cond evaluation + state select per
-# trip) without inflating tail waste: a lane overshoots by at most
-# unroll-1 dead-guarded sub-steps. On CPU the while_loop is cheap and the
-# masked sub-steps are pure overhead, so the default stays 1 there.
-DEFAULT_UNROLL = 4 if jax.default_backend() in ("tpu", "gpu") else 1
+def default_unroll() -> int:
+    """Work units per while_loop trip when the caller names none.
+
+    On lockstep accelerators (TPU/GPU) 4 amortizes the loop-carried
+    overhead (cond evaluation + state select per trip) without inflating
+    tail waste: a lane overshoots by at most unroll-1 dead-guarded
+    sub-steps. On CPU the while_loop is cheap and the masked sub-steps are
+    pure overhead, so the default stays 1 there. Asked at trace time, so
+    importing the package initializes no backend.
+    """
+    return 4 if jax.default_backend() in ("tpu", "gpu") else 1
 
 
 # --------------------------------------------------------------------- #
@@ -666,7 +671,7 @@ def traverse_impl(tree: Tree, segs: Segments, predicates, callback,
                   node_mask_wide: jax.Array | None = None,
                   wide_lanes: jax.Array | None = None,
                   use_range_mask: bool = False,
-                  unroll: int = DEFAULT_UNROLL) -> Trace:
+                  unroll: int | None = None) -> Trace:
     """Run one fused traversal per predicate lane, driving ``callback``.
 
     predicates: an :func:`intersects` or :func:`nearest` batch. Its
@@ -687,7 +692,11 @@ def traverse_impl(tree: Tree, segs: Segments, predicates, callback,
         lane by the boolean ``wide_lanes``; lanes flagged wide also get
         ``ctx.wide`` so a dual-mask visitor switches its gather mask
         (the split first main sweep, DESIGN.md §4).
+    unroll: work units per while-loop trip (``None``:
+        :func:`default_unroll`).
     """
+    if unroll is None:
+        unroll = default_unroll()
     m = segs.n_segments
     leaf_off = m - 1
     root = jnp.int32(0 if m > 1 else leaf_off)  # m==1: the single leaf
@@ -698,36 +707,80 @@ def traverse_impl(tree: Tree, segs: Segments, predicates, callback,
     if wide_lanes is None:
         wide_lanes = jnp.zeros_like(query_ids, dtype=bool)
 
-    def one_query(qid, lane_wide, q, q_self, q_dense, q_rank, carry0):
-        lane_on = qid >= 0
+    lanes = (q_arr, self_arr, dense_arr, rank_arr, wide_lanes)
+
+    def lane_step(q, q_self, q_dense, q_rank, lane_wide):
         ctx = QueryCtx(self_id=q_self, dense=q_dense, rank=q_rank,
                        wide=lane_wide)
-        step, live_of = make_step(tree, segs, callback, q=q, ctx=ctx,
-                                  lane_wide=lane_wide, r2=r2,
-                                  is_nearest=is_nearest,
-                                  node_mask=node_mask,
-                                  node_mask_wide=node_mask_wide,
-                                  use_range_mask=use_range_mask)
+        return make_step(tree, segs, callback, q=q, ctx=ctx,
+                         lane_wide=lane_wide, r2=r2, is_nearest=is_nearest,
+                         node_mask=node_mask, node_mask_wide=node_mask_wide,
+                         use_range_mask=use_range_mask)
 
-        def cond(state):
-            node, ptr, carry, evals, iters = state
-            return live_of(node, carry)
+    def lane_live(state, *lane):
+        node, ptr, carry, evals, iters = state
+        return lane_step(*lane)[1](node, carry)
 
-        def body(state):
-            node, ptr, carry, evals, iters = state
-            inner = (node, ptr, carry, evals)
-            for _ in range(unroll):
-                inner = step(inner)
-            return (*inner, iters + 1)
+    def lane_trip(state, *lane):
+        """One while-loop trip of one lane: ``unroll`` dead-guarded steps
+        (a finished lane stays frozen, as under a per-lane loop)."""
+        node, ptr, carry, evals, iters = state
+        step, live_of = lane_step(*lane)
+        live = live_of(node, carry)
+        inner = (node, ptr, carry, evals)
+        for _ in range(unroll):
+            inner = step(inner)
+        return (*inner, iters + jnp.where(live, 1, 0))
 
-        start = jnp.where(lane_on, root, jnp.int32(-1))
-        node, ptr, carry, evals, iters = lax.while_loop(
-            cond, body, (start, jnp.int32(-1), carry0, jnp.int32(0),
-                         jnp.int32(0)))
-        return Trace(carry=carry, evals=evals, iters=iters)
+    live_all = jax.vmap(lane_live)
+    trip_all = jax.vmap(lane_trip)
+    n_lanes = query_ids.shape[0]
+    zeros = jnp.zeros(n_lanes, jnp.int32)
+    state = (jnp.where(query_ids >= 0, root, jnp.int32(-1)),
+             jnp.full(n_lanes, -1, jnp.int32), carry, zeros, zeros)
+    out = state
+    pos = jnp.arange(n_lanes, dtype=jnp.int32)
+    widths = compact_widths(n_lanes)
+    for k, width in enumerate(widths):
+        nxt = widths[k + 1] if k + 1 < len(widths) else 0
 
-    return jax.vmap(one_query)(query_ids, wide_lanes, q_arr, self_arr,
-                               dense_arr, rank_arr, carry)
+        def cond(st, lanes=lanes, nxt=nxt):
+            return jnp.sum(live_all(st, *lanes)) > nxt
+
+        state = lax.while_loop(
+            cond, lambda st, lanes=lanes: trip_all(st, *lanes), state)
+        # write this stage's lanes back (finished ones are final; the
+        # still-live ones are overwritten by a later stage)
+        out = jax.tree.map(lambda o, x: o.at[pos].set(x, mode="drop"),
+                           out, state)
+        if nxt:
+            # at most nxt lanes are live: move them, in order, into a
+            # batch of width nxt; spare slots are dead and write nowhere
+            live = live_all(state, *lanes)
+            idx = jnp.nonzero(live, size=nxt, fill_value=0)[0]
+            spare = jnp.arange(nxt) >= jnp.sum(live)
+            state, lanes, pos = jax.tree.map(lambda x: x[idx],
+                                             (state, lanes, pos))
+            state = (jnp.where(spare, -1, state[0]),) + state[1:]
+            pos = jnp.where(spare, n_lanes, pos)
+    node, ptr, carry, evals, iters = out
+    return Trace(carry=carry, evals=evals, iters=iters)
+
+
+# Lane compaction (DESIGN.md §4): a batched walk costs every lane one trip
+# for as long as its slowest lane walks, so once at most a quarter of the
+# lanes are live they move into a batch a quarter as wide, down to this
+# width. Exact: lanes never interact.
+COMPACT_FACTOR = 4
+COMPACT_MIN_LANES = 2048
+
+
+def compact_widths(n_lanes: int) -> list:
+    """Batch widths a walk of ``n_lanes`` lanes compacts through."""
+    widths = [n_lanes]
+    while widths[-1] // COMPACT_FACTOR >= COMPACT_MIN_LANES:
+        widths.append(widths[-1] // COMPACT_FACTOR)
+    return widths
 
 
 # The jitted entry point. Callers already inside a traced context (the

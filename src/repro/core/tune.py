@@ -64,9 +64,8 @@ TUNE_UNROLLS = (1, 2, 4, 8)
 _SEARCH_LANE_TILES = (128, 256, 512)
 _SEARCH_UNROLLS = (1, 4)
 
-#: VMEM budget for whole-array index residency + per-lane walk state.
-#: Matches dispatch.PALLAS_MAX_INDEX_BYTES semantics: beyond this the
-#: kernel would spill, so candidate lane tiles are capped.
+#: VMEM budget for whole-array index residency + per-lane walk state:
+#: beyond this the kernel would spill, so candidate lane tiles are capped.
 VMEM_BUDGET_BYTES = 8 << 20
 
 #: Per-lane walk state footprint (node/ptr/carry/evals/iters + query
@@ -264,24 +263,17 @@ def stats_key(segs, eps: float, min_pts: int) -> tuple:
 def heuristic(segs, tree) -> TunedConfig:
     """Stats-driven config, no measurement.
 
-    On TPU the compiled kernel's (128, 4) defaults stand (they match the
-    VPU lane count and amortize the loop-carried overhead); the win there
-    is depth reordering plus the small-frontier fallback. Off-TPU the
-    kernel runs in interpret mode, where per-trip Python overhead
-    dominates: the widest in-budget lane tile with K=1 minimizes trips,
-    and measured phase costs (BENCH_traversal.json) show the reference
-    engine winning small compacted batches — hence the fallbacks.
+    The kernel runs only in interpret mode (it has no compiled TPU form,
+    ``repro.kernels.traverse.TPU_UNSUPPORTED``), where per-trip Python
+    overhead dominates: the widest in-budget lane tile with K=1 minimizes
+    trips, and measured phase costs (BENCH_traversal.json) show the
+    reference engine winning small compacted batches — hence the
+    fallbacks.
     """
-    tiles = lane_tiles_within_budget(_index_bytes(segs, tree))
-    if jax.default_backend() == "tpu":
-        fp = PhaseConfig("pallas", 128, 4, "depth")
-        sw = PhaseConfig("pallas", 128, 4, "depth")
-        bd = PhaseConfig("auto", 128, 4, "none")
-    else:
-        wide = max(tiles)
-        fp = PhaseConfig("pallas", wide, 1, "depth")
-        sw = PhaseConfig("pallas", wide, 1, "depth")
-        bd = PhaseConfig("auto", min(256, wide), 1, "none")
+    wide = max(lane_tiles_within_budget(_index_bytes(segs, tree)))
+    fp = PhaseConfig("pallas", wide, 1, "depth")
+    sw = PhaseConfig("pallas", wide, 1, "depth")
+    bd = PhaseConfig("auto", min(256, wide), 1, "none")
     return TunedConfig(first_pass=fp, sweep=sw, border=bd,
                        min_lanes=256, border_min_frac=0.9,
                        source="heuristic")

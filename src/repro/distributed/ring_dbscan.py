@@ -36,8 +36,9 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import grid, lbvh, morton, traversal
 from repro.core.fdbscan import DBSCANResult, _finalize
@@ -48,16 +49,20 @@ INT_MAX = jnp.iinfo(jnp.int32).max
 
 def _vary(x, axis, enabled=True):
     """Mark a loop-carry init as device-varying (shard_map VMA typing)."""
-    if not enabled:
-        return x
-    return sharding.vary(x, axis)
+    return lax.pcast(x, (axis,), to="varying") if enabled else x
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, check_vma=True):
     # check_vma=False is required when pl.pallas_call runs inside the body
     # (its out_shape ShapeDtypeStructs carry no varying-axes typing).
-    return sharding.shard_map_compat(fn, mesh, in_specs, out_specs,
-                                     check_vma=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
+
+
+def _replicated(mesh, *xs):
+    """Replicate sharded program outputs over the mesh, so host code can
+    slice and scatter them (an ``Explicit`` mesh types them sharded)."""
+    return jax.reshard(xs, NamedSharding(mesh, P()))
 
 
 def _count_tile(q, r, eps):
@@ -73,12 +78,12 @@ def _minlabel_tile(q, r, labels_r, mask_r, eps):
 
 def _pallas_count(q, r, eps):
     from repro.kernels import pairwise_count
-    return pairwise_count(q, r, eps, interpret=True)
+    return pairwise_count(q, r, eps)
 
 
 def _pallas_minlabel(q, r, labels_r, mask_r, eps):
     from repro.kernels import pairwise_minlabel
-    return pairwise_minlabel(q, r, labels_r, mask_r, eps, interpret=True)[0]
+    return pairwise_minlabel(q, r, labels_r, mask_r, eps)[0]
 
 
 def ring_dbscan(points, eps: float, min_pts: int, mesh=None,
@@ -183,7 +188,8 @@ def ring_dbscan(points, eps: float, min_pts: int, mesh=None,
     fn = _shard_map(kernel, mesh, in_specs=P(axis),
                     out_specs=(P(axis), P(axis), P(axis)),
                     check_vma=check_vma)
-    labels_pad, core_pad, sweeps_dev = jax.jit(fn)(pts_pad)
+    labels_pad, core_pad, sweeps_dev = _replicated(mesh,
+                                                   *jax.jit(fn)(pts_pad))
     labels_sorted = labels_pad[:n]   # -1 noise, else global sorted index
     core_sorted = core_pad[:n]
     labels, n_clusters = _finalize(labels_sorted, order, n)
@@ -222,6 +228,12 @@ def _sharded_programs(mesh, axis: str, n: int, n_pad: int, eps: float,
         active = blk_on & sharding.halo_mask(blk_pts, idx["lo"], idx["hi"],
                                              eps)
         return jnp.where(active, 0, jnp.int32(-1)), active
+
+    def total(evals):
+        """This shard's distance-eval count, as a (1,) block: the host
+        adds the per-device counts in int64, where the mesh-wide total
+        cannot overflow."""
+        return jnp.sum(evals)[None]
 
     def jump(labels):
         # all-gather + pointer jumping (labels are global sorted indices;
@@ -270,7 +282,7 @@ def _sharded_programs(mesh, axis: str, n: int, n_pad: int, eps: float,
             0, ndev, count_body, (local_pts, valid, zero_i, zero_i))
         core = (counts >= min_pts) & valid
         labels0 = jnp.where(core, gid, INT_MAX)
-        return idx, core, labels0, evals
+        return idx, core, labels0, total(evals)
 
     def minlabel_rotation(local_pts, idx, point_vals, gather_mask, blk_on,
                           acc0):
@@ -308,7 +320,7 @@ def _sharded_programs(mesh, axis: str, n: int, n_pad: int, eps: float,
         new = jnp.where(core, jnp.minimum(labels, best), labels)
         new = jump(new)
         changed = lax.psum(jnp.any(new != labels).astype(jnp.int32), axis)
-        return new, jnp.reshape(changed > 0, (1,)), evals
+        return new, changed > 0, total(evals)
 
     def border_kernel(local_pts, idx, core, labels):
         """Borders: one rotation of the non-core queries over core roots."""
@@ -320,15 +332,21 @@ def _sharded_programs(mesh, axis: str, n: int, n_pad: int, eps: float,
                                         valid & ~core,
                                         jnp.full(n_loc, INT_MAX, jnp.int32))
         labels = jnp.where(core, labels, jnp.where(valid, best, INT_MAX))
-        return jnp.where(labels == INT_MAX, jnp.int32(-1), labels), evals
+        return (jnp.where(labels == INT_MAX, jnp.int32(-1), labels),
+                total(evals))
 
     # check_vma=False: the traversal engine's loop carries mix replicated
     # constants with device-varying state; its while_loops carry no
     # collectives, so the replication checker's complaint is spurious here.
-    def smap(fn, n_in):
+    # Per-point state and eval counts stay sharded over the data axis; the
+    # psum'd change flag comes out replicated, so the host loop reads it
+    # without touching a sharded array.
+    def smap(fn, n_in, out_specs):
         return jax.jit(_shard_map(fn, mesh, in_specs=(P(axis),) * n_in,
-                                  out_specs=P(axis), check_vma=False))
-    return smap(build_kernel, 1), smap(sweep_kernel, 4), smap(border_kernel, 4)
+                                  out_specs=out_specs, check_vma=False))
+    return (smap(build_kernel, 1, P(axis)),
+            smap(sweep_kernel, 4, (P(axis), P(), P(axis))),
+            smap(border_kernel, 4, P(axis)))
 
 
 def tree_dbscan_sharded(points, eps: float, min_pts: int, mesh=None,
@@ -394,22 +412,28 @@ def tree_dbscan_sharded(points, eps: float, min_pts: int, mesh=None,
                       "n_sweeps": res.n_sweeps}) if with_stats else res
     pts_pad = jnp.pad(pts_sorted, ((0, n_pad - n), (0, 0)),
                       constant_values=1e30)  # sentinels never match
+    # each device receives only its own slab of the sorted points
+    pts_pad = jax.device_put(pts_pad, NamedSharding(mesh, P(axis)))
     build_fn, sweep_fn, border_fn = _sharded_programs(
         mesh, axis, n, n_pad, float(eps), int(min_pts))
 
+    def host_total(evals):
+        return int(np.sum(jax.device_get(evals), dtype=np.int64))
+
     idx, core_pad, labels_pad, evals = build_fn(pts_pad)
-    total_evals = int(jnp.sum(evals))
+    total_evals = host_total(evals)
     n_sweeps = 0
     while True:
         labels_pad, changed, evals = sweep_fn(pts_pad, idx, core_pad,
                                               labels_pad)
         n_sweeps += 1
-        total_evals += int(jnp.sum(evals))
-        if not bool(changed[0]):
+        total_evals += host_total(evals)
+        if not bool(changed):
             break
     labels_pad, evals = border_fn(pts_pad, idx, core_pad, labels_pad)
-    total_evals += int(jnp.sum(evals))
+    total_evals += host_total(evals)
 
+    labels_pad, core_pad = _replicated(mesh, labels_pad, core_pad)
     labels_sorted = labels_pad[:n]
     core_sorted = core_pad[:n]
     labels, n_clusters = _finalize(labels_sorted, order, n)
@@ -425,5 +449,8 @@ def tree_dbscan_sharded(points, eps: float, min_pts: int, mesh=None,
         "distance_evals": total_evals,
         "ring_distance_evals": (2 + n_sweeps) * n_pad * n_pad,
         "ndev": ndev, "n_pad": n_pad, "n_sweeps": n_sweeps,
+        # resident slab rows per device: the points really are split
+        "shard_rows": {str(sh.device): int(sh.data.shape[0])
+                       for sh in pts_pad.addressable_shards},
     }
     return res, stats

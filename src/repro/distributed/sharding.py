@@ -1,14 +1,8 @@
-"""Mesh/shard_map utilities shared by the distributed DBSCAN paths.
+"""Slab geometry shared by the distributed DBSCAN paths (DESIGN.md §6).
 
-Two kinds of helpers live here:
-
-  * jax API compatibility shims (:func:`vary`, :func:`shard_map_compat`)
-    so the collective programs lower across the ``shard_map`` /
-    VMA-typing renames;
-  * slab geometry for the sharded tree path (DESIGN.md §6):
-    :func:`shard_bounds` fits a shard's resident AABB and
-    :func:`halo_mask` is the eps-dilated membership test that decides
-    which traveling queries must traverse a remote shard's tree at all.
+:func:`shard_bounds` fits a shard's resident AABB and :func:`halo_mask`
+is the eps-dilated membership test that decides which traveling queries
+must traverse a remote shard's tree at all.
 """
 from __future__ import annotations
 
@@ -17,39 +11,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
-
-
-def vary(x, axis: str):
-    """Mark ``x`` device-varying under shard_map's VMA typing.
-
-    Version shim: newer jax spells this ``lax.pcast(..., to="varying")``
-    (earlier ``lax.pvary``); on jax without VMA typing it is a no-op —
-    replication is then governed by ``check_rep`` (see shard_map_compat).
-    """
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is not None:
-        return pc(x, (axis,), to="varying")
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None:
-        return pv(x, (axis,))
-    return x
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs, check_vma: bool = True):
-    """shard_map across the jax API renames.
-
-    Newer jax: top-level ``jax.shard_map`` with ``check_vma``. Older jax:
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` — which we
-    always disable there, since without VMA typing (``vary`` above being a
-    no-op) its replication checker rejects valid loop-carried collectives.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 def shard_bounds(pts: jax.Array, valid: jax.Array

@@ -2,7 +2,7 @@
 
 ``dbscan_tiled`` is the TPU-native dense backend (DESIGN.md §3): the whole
 two-phase PDSDBSCAN framework of the paper, but with neighbor determination
-done by streaming MXU distance tiles instead of a tree walk. It is the
+done by streaming distance tiles instead of a tree walk. It is the
 backend of choice when points/chip is small enough that n^2/chips tiles are
 cheaper than divergent traversal (and it is what the distributed ring
 version in repro.distributed.ring_dbscan runs per step). Memory stays O(n):
@@ -21,7 +21,8 @@ from . import ref as kernel_ref  # noqa: F401  (re-exported for benchmarks)
 
 
 @partial(jax.jit, static_argnames=("min_pts", "interpret", "tile"))
-def _tiled_phases(pts, eps, min_pts: int, interpret: bool, tile: int):
+def _tiled_phases(pts, eps, min_pts: int, interpret: bool | None,
+                  tile: int):
     n = pts.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
 
@@ -59,8 +60,8 @@ def _tiled_phases(pts, eps, min_pts: int, interpret: bool, tile: int):
 
 
 def dbscan_tiled(points, eps: float, min_pts: int, *, star: bool = False,
-                 interpret: bool = True, tile: int = 128):
-    """Full DBSCAN on MXU distance tiles (labels compacted, noise = -1).
+                 interpret: bool | None = None, tile: int = 128):
+    """Full DBSCAN on Pallas distance tiles (labels compacted, noise = -1).
 
     Unlike the paper's GPU preprocessing skip for minpts == 2, the tiled
     backend keeps the uniform count pass: a saturating count over dense

@@ -37,7 +37,9 @@ kernel's traversal work exactly like the engine's.
 
 On CPU (and GPU, where the TPU compiler params do not apply) the kernel
 runs in Pallas interpret mode — the CI path that keeps the kernel body
-exercised on every commit.
+exercised on every commit. On TPU it has no compiled form yet
+(:data:`TPU_UNSUPPORTED`), and :func:`traverse` raises rather than run
+the emulator there.
 """
 from __future__ import annotations
 
@@ -48,13 +50,26 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import morton, traversal
 from repro.core.grid import Segments
 from repro.core.lbvh import Tree
-from .pairwise import SMEM, CompilerParams
+from .pairwise import resolve_interpret
 
 INT_MAX = traversal.INT_MAX
+
+#: Why the kernel cannot run on a TPU: the walk reads the index per lane
+#: (``pts[ptr]``, ``box_lo[node]``, ropes, segment tables) with a
+#: ``(LANE_TILE,)`` vector of data-dependent indices, and Mosaic lowers a
+#: gather only when input, indices and output share one shape (a
+#: within-vreg ``take_along_axis``).
+TPU_UNSUPPORTED = (
+    "the pallas-tree traversal kernel does not compile for TPU: Mosaic "
+    "refuses its per-lane gathers from the VMEM-resident index "
+    "(_gather_lowering_rule: 'Shape mismatch in input, indices and "
+    "output'); use algorithm='fdbscan' or 'auto', which run the vmapped "
+    "walk")
 
 # Queries per kernel block. 128 matches the VPU lane count; each block's
 # walk state is a few (128,) vectors, and a block retires when its slowest
@@ -251,7 +266,8 @@ def _run(cfg: _Cfg, lane_tile: int, interpret: bool,
 
     scalar_inputs = [jnp.full((1, 1), r2, pts.dtype),
                      jnp.full((1, 1), cap, jnp.int32)]
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=SMEM)
+    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
+                               memory_space=pltpu.MemorySpace.SMEM)
 
     operands = ([x for x, _ in lane_inputs] + full_inputs + scalar_inputs)
     in_specs = ([spec or lane_spec for _, spec in lane_inputs]
@@ -269,7 +285,8 @@ def _run(cfg: _Cfg, lane_tile: int, interpret: bool,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
     outs = (acc[:L], hits[:L], evals[:L], iters[:L])
@@ -307,8 +324,9 @@ def traverse(tree: Tree, segs: Segments, predicates, callback, carry=None,
             :data:`PALLAS_UNROLL`; the engine's backend-adaptive default
             does not apply — the kernel is always lane-tiled).
         lane_tile: queries per kernel block (default :data:`LANE_TILE`).
-        interpret: force Pallas interpret mode; default auto — compiled
-            on TPU, interpreted elsewhere (the CPU CI path).
+        interpret: Pallas interpret mode; default interpreted off TPU
+            (the CPU CI path). A compiled launch raises
+            ``NotImplementedError`` (:data:`TPU_UNSUPPORTED`).
         reorder: lane-permutation policy — ``"none"`` (default, today's
             launch order), ``"morton"`` (sort lanes by the query points'
             Morton code so a tile walks correlated subtrees), or
@@ -336,12 +354,12 @@ lane_sort_key` and DESIGN.md §9).
             tree, segs, predicates, callback, carry=carry,
             node_mask=node_mask, node_mask_wide=node_mask_wide,
             wide_lanes=wide_lanes, use_range_mask=use_range_mask,
-            unroll=(traversal.DEFAULT_UNROLL if unroll is None
-                    else unroll))
+            unroll=unroll)
     if unroll is None:
         unroll = PALLAS_UNROLL
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    if not interpret:
+        raise NotImplementedError(TPU_UNSUPPORTED)
 
     (query_ids, q_arr, self_arr, dense_arr, rank_arr, external, r2,
      _) = traversal.lane_arrays(segs, predicates, use_range_mask)

@@ -59,10 +59,7 @@ def _run(args, reg, tracer):
           f"eps={args.eps} minpts={args.minpts} algo={args.algorithm}")
 
     t0 = time.time()
-    if args.algorithm == "tiled":
-        from repro.kernels import dbscan_tiled
-        res = dbscan_tiled(pts, args.eps, args.minpts)
-    elif args.algorithm == "gdbscan":
+    if args.algorithm == "gdbscan":
         from repro.core import gdbscan
         res = gdbscan(pts, args.eps, args.minpts)
     elif args.algorithm == "ring":
@@ -95,4 +92,6 @@ def _run(args, reg, tracer):
 
 
 if __name__ == "__main__":
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     main()

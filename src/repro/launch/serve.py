@@ -508,4 +508,6 @@ def _serve_multi(args, reg, tracer):
 
 
 if __name__ == "__main__":
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     main()

@@ -24,7 +24,7 @@ mode: watches are recorded as ``"none"`` and nothing ever blocks.
 ``jax.profiler.TraceAnnotation``, so when a JAX profiler capture is
 active (e.g. under :func:`profiler_session`) the same phase names appear
 on the profiler timeline; without an active capture the annotation is a
-cheap no-op, and on builds without the profiler it degrades gracefully.
+cheap no-op.
 
 Disabled-by-default: with no tracer installed, :func:`span` returns a
 shared no-op context manager — one module-global load per call site.
@@ -71,20 +71,15 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        synced = False
-        if self._watched:
-            try:
-                import jax
-                jax.block_until_ready(jax.tree.leaves(self._watched))
-                synced = True
-            except Exception:
-                pass
+        # sync only a span whose body succeeded: a device fault surfacing
+        # here must propagate, but must not replace the body's own error
+        synced = bool(self._watched) and exc_type is None
+        if synced:
+            import jax
+            jax.block_until_ready(jax.tree.leaves(self._watched))
         t1 = time.perf_counter()
         if self._ann is not None:
-            try:
-                self._ann.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -232,40 +227,26 @@ def watch(*values) -> None:
 # ---------------------------------------------------------------------- #
 
 def _enter_annotation(name: str):
-    """Enter a ``jax.profiler.TraceAnnotation(name)`` if available; the
-    annotation is visible only while a profiler capture is active."""
-    try:
-        from jax import profiler
-        ann = profiler.TraceAnnotation(name)
-        ann.__enter__()
-        return ann
-    except Exception:
-        return None
+    """Enter a ``jax.profiler.TraceAnnotation(name)``; the annotation is
+    visible only while a profiler capture is active."""
+    from jax import profiler
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 @contextmanager
 def profiler_session(log_dir: str):
     """Bracket a region with a JAX profiler capture (the
     ``cuda_profile_hook`` shape: arm the vendor profiler around exactly
-    the region of interest).  Yields True when a capture actually
-    started; degrades to a no-op (yielding False) on builds without
-    profiler support, so call sites never need to gate on it."""
-    started = False
+    the region of interest). A capture that cannot start or stop raises:
+    a missing trace must not pass for an empty one."""
+    import jax
+    jax.profiler.start_trace(log_dir)
     try:
-        import jax
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield started
+        yield
     finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------- #

@@ -194,9 +194,9 @@ def test_dispatch_explicit_backend():
 
 
 def test_dispatch_auto_upgrades_on_accelerator(monkeypatch):
-    # auto dispatch picks the kernel engine whenever jit runs on TPU;
-    # pin the probe (CPU CI) and check only the *plan* — the kernel still
-    # runs in interpret mode here
+    # on TPU the kernel has no compiled form (kt.TPU_UNSUPPORTED), so auto
+    # dispatch no longer upgrades a tree decision there: pin the probe
+    # (CPU CI) and check that the plan and the labels stay the tree's
     pts = pointclouds.load("blobs", 2000)   # > TILED_MAX_POINTS
     dispatch.clear_cache()
     ref = dispatch.dbscan(pts, EPS, MINPTS, algorithm="auto")  # CPU: tree
@@ -204,29 +204,30 @@ def test_dispatch_auto_upgrades_on_accelerator(monkeypatch):
     monkeypatch.setattr(dispatch, "_accel", lambda: True)
     dispatch.clear_cache()
     p = dispatch.plan(pts, EPS, MINPTS, algorithm="auto")
-    assert p.backend == "pallas-tree"
-    assert "pallas" in p.stats["reason"]
-    # same auto decision, same index, upgraded engine: identical labels
+    assert p.backend == ref.backend
+    assert "pallas" not in p.stats["reason"]
     res = dispatch.dbscan(pts, EPS, MINPTS, query_plan=p)
-    assert res.backend == "pallas-tree"
     np.testing.assert_array_equal(np.asarray(res.labels),
                                   np.asarray(ref.labels))
     np.testing.assert_array_equal(np.asarray(res.core_mask),
                                   np.asarray(ref.core_mask))
+    dispatch.clear_cache()
 
 
 def test_auto_upgrade_respects_vmem_budget(monkeypatch):
-    # past the kernel's VMEM residency budget auto dispatch must keep the
-    # reference engine (a compile failure is worse than a slower walk);
-    # an explicit pallas-tree request still bypasses the guard
-    monkeypatch.setattr(dispatch, "_accel", lambda: True)
-    monkeypatch.setattr(dispatch, "PALLAS_MAX_INDEX_BYTES", 1024)
+    # an explicit pallas-tree request on TPU raises, naming the compiler's
+    # refusal, instead of running the kernel in interpret mode; off TPU
+    # the same request runs the (interpreted) kernel
     dispatch.clear_cache()
     pts = pointclouds.load("blobs", 2000)
-    p = dispatch.plan(pts, EPS, MINPTS, algorithm="auto")
-    assert p.backend != "pallas-tree"
-    p2 = dispatch.plan(pts, EPS, MINPTS, algorithm="pallas-tree")
-    assert p2.backend == "pallas-tree"
+    assert dispatch.plan(pts, EPS, MINPTS,
+                         algorithm="pallas-tree").backend == "pallas-tree"
+    monkeypatch.setattr(dispatch, "_accel", lambda: True)
+    dispatch.clear_cache()
+    with pytest.raises(NotImplementedError, match="Mosaic refuses"):
+        dispatch.plan(pts, EPS, MINPTS, algorithm="pallas-tree")
+    with pytest.raises(NotImplementedError, match="Mosaic refuses"):
+        dispatch.dbscan(pts, EPS, MINPTS, algorithm="pallas-tree")
     dispatch.clear_cache()
 
 

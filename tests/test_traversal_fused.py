@@ -6,6 +6,7 @@ sweeps; the unrolled loop body is result-invariant; the per-run traversal
 budget is `n_sweeps + 1`.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -191,3 +192,53 @@ def test_minpts2_uses_fused_pass():
     res = dbscan(pts, 0.05, 2, algorithm="fdbscan")
     check_dbscan(pts, 0.05, 2, res.labels, res.core_mask)
     assert res.n_traversals == res.n_sweeps + 1
+
+
+@pytest.mark.parametrize("kind", ["count", "minlabel", "count_minlabel",
+                                  "nearest", "frontier", "external"])
+def test_lane_compaction_is_exact(kind, monkeypatch):
+    # Compacting the live lanes into narrower batches mid-walk moves lanes
+    # but never changes one: every per-lane output matches the walk that
+    # keeps all lanes at full width, work counters included.
+    pts = separated_points(300, 2, eps=0.1, seed=12)
+    segs, tree = _index(pts, "fdbscan-densebox", eps=0.1, mp=4)
+    n = segs.n_points
+    sphere = traversal.sphere(0.1)
+    pred = traversal.intersects(sphere)
+    cb = _visitor(kind if kind in ("count", "count_minlabel")
+                  else "minlabel", n, cap=traversal.INT_MAX)
+    if kind == "nearest":
+        cb, pred = traversal.KNNVisitor(4), traversal.nearest(4)
+    elif kind == "frontier":
+        ids = np.where(np.arange(n) % 3 == 0, np.arange(n), -1)
+        pred = traversal.intersects(sphere, ids=jnp.asarray(ids, jnp.int32))
+    elif kind == "external":
+        pred = traversal.intersects(sphere, pts=segs.pts[::-1] + 0.01)
+
+    def walk(min_lanes):
+        monkeypatch.setattr(traversal, "COMPACT_MIN_LANES", min_lanes)
+        return traversal.traverse_impl(tree, segs, pred, cb)
+
+    assert len(traversal.compact_widths(n)) == 1
+    full = walk(traversal.COMPACT_MIN_LANES)
+    compacted = walk(8)
+    assert traversal.compact_widths(n) == [n, n // 4, n // 16]
+    for a, b in zip(jax.tree.leaves(full), jax.tree.leaves(compacted)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(full.evals.sum()) > 0
+
+
+@pytest.mark.parametrize("algo", ["fdbscan", "fdbscan-densebox"])
+def test_lane_compaction_keeps_labels(algo, monkeypatch):
+    pts = separated_points(400, 2, eps=0.06, seed=13)
+    segs, tree = _index(pts, algo, eps=0.06, mp=5)
+    ref = fdbscan.cluster_from_index(segs, tree, 0.06, 5)
+    monkeypatch.setattr(traversal, "COMPACT_MIN_LANES", 8)
+    jax.clear_caches()
+    got = fdbscan.cluster_from_index(segs, tree, 0.06, 5)
+    jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(ref.labels),
+                                  np.asarray(got.labels))
+    np.testing.assert_array_equal(np.asarray(ref.core_mask),
+                                  np.asarray(got.core_mask))
+    assert ref.n_sweeps == got.n_sweeps
