@@ -1,0 +1,13 @@
+"""border_s: seconds per call inside the program's ``border`` spans (the
+walk that gives each non-core point its cluster, ``core/fdbscan.py``),
+timed by the program's tracer in sync mode.
+
+Read only beside a device trace of the same run: off the chip the span
+times XLA's CPU backend, which is no measurement of the chip."""
+
+
+def read(run):
+    spans = [e for e in run.spans if e["name"] == "border"]
+    if not spans or not run.calls or run.device is None:
+        return None
+    return sum(e["dur"] for e in spans) / 1e6 / len(run.calls)

@@ -168,7 +168,10 @@ def _cache_put(key, val):
 def _tree_of(segs: grid.Segments):
     if segs.n_segments < 2 or segs.n_points < 2:
         return None
-    return lbvh.build_tree(segs.codes, segs.prim_lo, segs.prim_hi)
+    with obs_trace.span("lbvh", n_segments=segs.n_segments) as sp:
+        tree = lbvh.build_tree(segs.codes, segs.prim_lo, segs.prim_hi)
+        sp.watch(tree)
+    return tree
 
 
 def _fdbscan_plan(points, pkey: str, stats: dict) -> Plan:
@@ -193,7 +196,8 @@ def plan(points, eps: float, min_pts: int,
 
     Instrumented (DESIGN.md §12): with a collector installed, planning is
     bracketed by a ``plan`` span (index builds get a nested ``build``
-    span) and reports plan/cache-hit counters per backend; with none
+    span, the LBVH an ``lbvh`` span) and reports plan/cache-hit counters
+    per backend; with none
     installed every instrumentation point is a no-op and the result is
     bit-identical.
 
@@ -376,7 +380,6 @@ def dbscan(points, eps: float, min_pts: int, *, algorithm: str = "auto",
                        frontier=frontier, mesh=mesh, axis=axis)
         sp.watch(res.labels, res.core_mask)
     obs_metrics.inc("dbscan_runs_total", backend=p.backend)
-    obs_metrics.observe("dbscan_sweeps", res.n_sweeps, backend=p.backend)
     return res
 
 

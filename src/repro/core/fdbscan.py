@@ -169,17 +169,32 @@ def _engine_name(traverse_fn) -> str:
     return "reference" if traverse_fn is traversal.traverse else "pallas"
 
 
-def _record_trace(phase: str, engine: str, tr) -> None:
-    """Fold a traversal Trace's work counters into the active metrics
-    registry (DESIGN.md §12).  Reading the counters forces a device sync,
-    so this is gated on an installed registry — with none, the traversal
-    result is never touched and timing is unperturbed."""
-    if obs_metrics.active() is None:
+def _walk_log() -> list | None:
+    """A list for :func:`_record_walk` while a metrics registry is
+    installed, else None (nothing is kept)."""
+    return [] if obs_metrics.active() is not None else None
+
+
+def _record_walk(walks: list | None, phase: str, engine: str, tr) -> None:
+    """Keep a walk's per-lane ``evals`` for :func:`_fold_walks`. Keeping
+    a device array reads nothing, so the walk's timing is unperturbed."""
+    if walks is not None:
+        walks.append((phase, engine, tr.evals))
+
+
+def _fold_walks(walks: list | None) -> None:
+    """Fold the kept walks' work counters into
+    ``traversal_evals_total{phase=,engine=}`` (DESIGN.md §12): one host
+    transfer of every walk's ``evals`` and a numpy sum, once the caller
+    has synced on its result anyway — no device reduction, no sync per
+    walk, no program of its own."""
+    if not walks:
         return
-    obs_metrics.inc("traversal_evals_total", float(jnp.sum(tr.evals)),
-                    phase=phase, engine=engine)
-    obs_metrics.inc("traversal_iters_total", float(jnp.sum(tr.iters)),
-                    phase=phase, engine=engine)
+    host = jax.device_get([evals for _, _, evals in walks])
+    for (phase, engine, _), evals in zip(walks, host):
+        obs_metrics.inc("traversal_evals_total",
+                        float(np.sum(evals, dtype=np.int64)),
+                        phase=phase, engine=engine)
 
 
 def _gather_minlabel(tree, segs, eps, labels, gather_mask, ids,
@@ -258,7 +273,7 @@ def _near_changed(keys: np.ndarray, d: int, changed_np: np.ndarray
 def _sweep_to_fixpoint(tree, segs, eps, core, labels0, *,
                        frontier: bool = True, collect_stats: bool = False,
                        fused_init=None, traverse_fn=traversal.traverse,
-                       tune=None):
+                       tune=None, walks=None):
     """Hook+jump sweeps until the core-core components stabilize.
 
     Frontier restriction (DESIGN.md §4): labels only ever decrease and the
@@ -272,51 +287,64 @@ def _sweep_to_fixpoint(tree, segs, eps, core, labels0, *,
     neighbor such a cell re-discover it through the unpruned walk. Labels
     and sweep counts are identical to full sweeps; only the work shrinks.
 
+    The host work around the walks is spanned as ``frontier``: stage
+    ``setup`` before the first sweep, stage ``next`` after each (fetching
+    the change flags and compacting the next walk's lanes), with the
+    change count and the next walk's padded lane count (0 once the loop
+    ends). These spans watch nothing: the device work they enqueue stays
+    in the sweep that waits on it. ``walks`` (see :func:`_walk_log`)
+    keeps each sweep's work counters.
+
     Returns (labels, sweeps, stats) with per-sweep frontier sizes and
     loop-trip totals.
     """
     n = segs.n_points
     d = segs.pts.shape[1]
-    core_np = np.asarray(core)
-    n_core = int(core_np.sum())
-    # Query-side restriction only pays once the frontier is genuinely
-    # small; above this the cell filter is host overhead for nothing.
-    small = max(_PAD_MIN, n_core // 4)
-    labels = labels0
-    ids_core = _compact_ids(core_np)  # default: every core point gathers
-    ids = ids_core
-    gather_mask = core            # sweep 1 is full: nothing gathered yet
-    # every gather mask is a subset of core, so subtrees holding only
-    # non-core points (noise regions) are prunable from sweep one on
-    node_mask_core = _frontier_node_mask(tree, segs, core)
-    node_mask = node_mask_core
-    # eps <= 0 is degenerate (no grid): skip the cell filter, keep the
-    # (still exact) gather-mask + node-mask frontier restriction
-    cell_keys = _cell_keys(segs.pts, eps) if frontier and eps > 0 else None
-    dual = None
-    gather_wide = None            # wide lanes' gather mask (split sweep 1)
-    if frontier and fused_init is not None:
-        # Split first sweep: queries that absorbed every initial value in
-        # the fused pass gather changed-since-init points only (narrow);
-        # the validation-rejected minority gathers the full core set
-        # (wide). One walk, per-lane mask choice — exact either way.
-        vals0, absorbed = fused_init
-        changed0 = core & (labels0 != vals0)
-        changed0_np = np.asarray(changed0)
-        wide_np = core_np & ~np.asarray(absorbed)
-        if cell_keys is not None and int(changed0_np.sum()) <= small:
-            near0 = (_near_changed(cell_keys, d, changed0_np)
-                     if changed0_np.any() else np.zeros(n, bool))
-            active_np = wide_np | (core_np & near0)
-            ids = _compact_ids(active_np)
-            ids_np = np.asarray(ids)
-            lane_wide = jnp.asarray(
-                np.where(ids_np >= 0, wide_np[np.maximum(ids_np, 0)], False))
-            gather_mask = changed0
-            gather_wide = core
-            dual = dict(wide_lanes=lane_wide,
-                        node_mask_wide=node_mask_core)
-            node_mask = _frontier_node_mask(tree, segs, changed0)
+    with obs_trace.span("frontier", stage="setup") as fsp:
+        core_np = np.asarray(core)
+        n_core = int(core_np.sum())
+        # Query-side restriction only pays once the frontier is genuinely
+        # small; above this the cell filter is host overhead for nothing.
+        small = max(_PAD_MIN, n_core // 4)
+        labels = labels0
+        ids_core = _compact_ids(core_np)  # default: every core point gathers
+        ids = ids_core
+        gather_mask = core            # sweep 1 is full: nothing gathered yet
+        # every gather mask is a subset of core, so subtrees holding only
+        # non-core points (noise regions) are prunable from sweep one on
+        node_mask_core = _frontier_node_mask(tree, segs, core)
+        node_mask = node_mask_core
+        # eps <= 0 is degenerate (no grid): skip the cell filter, keep the
+        # (still exact) gather-mask + node-mask frontier restriction
+        cell_keys = (_cell_keys(segs.pts, eps) if frontier and eps > 0
+                     else None)
+        dual = None
+        gather_wide = None            # wide lanes' gather mask (split sweep 1)
+        if frontier and fused_init is not None:
+            # Split first sweep: queries that absorbed every initial value
+            # in the fused pass gather changed-since-init points only
+            # (narrow); the validation-rejected minority gathers the full
+            # core set (wide). One walk, per-lane mask choice — exact
+            # either way.
+            vals0, absorbed = fused_init
+            changed0 = core & (labels0 != vals0)
+            changed0_np = np.asarray(changed0)
+            wide_np = core_np & ~np.asarray(absorbed)
+            if cell_keys is not None and int(changed0_np.sum()) <= small:
+                near0 = (_near_changed(cell_keys, d, changed0_np)
+                         if changed0_np.any() else np.zeros(n, bool))
+                active_np = wide_np | (core_np & near0)
+                ids = _compact_ids(active_np)
+                ids_np = np.asarray(ids)
+                lane_wide = jnp.asarray(
+                    np.where(ids_np >= 0, wide_np[np.maximum(ids_np, 0)],
+                             False))
+                gather_mask = changed0
+                gather_wide = core
+                dual = dict(wide_lanes=lane_wide,
+                            node_mask_wide=node_mask_core)
+                node_mask = _frontier_node_mask(tree, segs, changed0)
+        fsp.set(lanes=int(ids.shape[0]))
     sweeps = 0
     stats = {"frontier_per_sweep": [], "active_per_sweep": [],
              "iters_per_sweep": [], "evals_per_sweep": []}
@@ -334,7 +362,8 @@ def _sweep_to_fixpoint(tree, segs, eps, core, labels0, *,
             if rank is not None:
                 rank_kw = {"depth_rank": rank}
         engine = _engine_name(sweep_fn)
-        with obs_trace.span("sweep", i=sweeps + 1, engine=engine) as sp:
+        with obs_trace.span("sweep", i=sweeps + 1, engine=engine,
+                            lanes=int(ids.shape[0])) as sp:
             tr = sweep_fn(
                 tree, segs,
                 traversal.intersects(traversal.sphere(eps), ids=ids),
@@ -346,7 +375,7 @@ def _sweep_to_fixpoint(tree, segs, eps, core, labels0, *,
             new, changed, changed_flags = _post_sweep(tree, segs, labels,
                                                       core, ids, tr.acc)
             sp.watch(new, changed)
-        _record_trace("sweep", engine, tr)
+        _record_walk(walks, "sweep", engine, tr)
         sweeps += 1
         if collect_stats:
             stats["frontier_per_sweep"].append(int(jnp.sum(gather_mask)))
@@ -354,22 +383,25 @@ def _sweep_to_fixpoint(tree, segs, eps, core, labels0, *,
             stats["iters_per_sweep"].append(int(jnp.sum(tr.iters)))
             stats["evals_per_sweep"].append(int(jnp.sum(tr.evals)))
         labels = new
-        changed_np = np.asarray(changed)
-        n_changed = int(changed_np.sum())
+        with obs_trace.span("frontier", stage="next") as fsp:
+            changed_np = np.asarray(changed)
+            n_changed = int(changed_np.sum())
+            if n_changed and frontier:
+                # gather only from changed points; prune unchanged
+                # subtrees; and, once the frontier is small, re-traverse
+                # only queries whose eps-cell neighborhood holds a changed
+                # point (anyone else provably cannot improve)
+                gather_mask = changed
+                node_mask = changed_flags
+                if cell_keys is not None and n_changed <= small:
+                    ids = _compact_ids(core_np & _near_changed(
+                        cell_keys, d, changed_np))
+                else:
+                    ids = ids_core
+            fsp.set(n_changed=n_changed,
+                    lanes=int(ids.shape[0]) if n_changed else 0)
         if n_changed == 0:
             break
-        if frontier:
-            # gather only from changed points; prune unchanged subtrees;
-            # and, once the frontier is small, re-traverse only queries
-            # whose eps-cell neighborhood holds a changed point (anyone
-            # else provably cannot improve)
-            gather_mask = changed
-            node_mask = changed_flags
-            if cell_keys is not None and n_changed <= small:
-                ids = _compact_ids(core_np & _near_changed(cell_keys, d,
-                                                           changed_np))
-            else:
-                ids = ids_core
     return labels, sweeps, stats
 
 
@@ -385,7 +417,7 @@ def _main_phase(tree, segs, eps, core, *, frontier: bool = True):
 
 
 def _assign_borders(tree, segs, eps, core, core_labels,
-                    traverse_fn=traversal.traverse, tune=None):
+                    traverse_fn=traversal.traverse, tune=None, walks=None):
     """Borders take the min adjacent core root; isolated non-core -> noise.
 
     Traverses a compacted non-core query set (usually a small minority),
@@ -405,7 +437,7 @@ def _assign_borders(tree, segs, eps, core, core_labels,
                                                                   core),
                                     traverse_fn=traverse_fn,
                                     depth_rank=depth_rank)
-    _record_trace("border", _engine_name(traverse_fn), tr)
+    _record_walk(walks, "border", _engine_name(traverse_fn), tr)
     labels = jnp.where(core, core_labels, gathered)
     return jnp.where(labels == INT_MAX, jnp.int32(-1), labels)
 
@@ -444,6 +476,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
     """
     n = segs.n_points
     stats: dict = {}
+    walks = _walk_log()
     # the walk's execution engine, resolved once for every phase below
     traverse_fn = traversal.traverse
     if backend == "pallas-tree":
@@ -484,7 +517,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
             tree, segs, eps, min_pts, traverse_fn=fp_fn,
             depth_rank=fp_rank)
         sp.watch(core, labels0)
-    _record_trace("first_pass", engine, first)
+    _record_walk(walks, "first_pass", engine, first)
     if tune is not None:
         # The pass's per-query loop-trip counts are the depth oracle for
         # every later reorder="depth" traversal over this plan (free: the
@@ -493,7 +526,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
     core_labels, loop_sweeps, sweep_stats = _sweep_to_fixpoint(
         tree, segs, eps, core, labels0, frontier=frontier,
         collect_stats=with_stats, fused_init=(vals0, absorbed),
-        traverse_fn=traverse_fn, tune=tune)
+        traverse_fn=traverse_fn, tune=tune, walks=walks)
     n_sweeps = 1 + loop_sweeps          # the fused pass is sweep #1
     n_traversals = n_sweeps
 
@@ -504,7 +537,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
             labels_sorted = _assign_borders(tree, segs, eps, core,
                                             core_labels,
                                             traverse_fn=traverse_fn,
-                                            tune=tune)
+                                            tune=tune, walks=walks)
             sp.watch(labels_sorted)
         n_traversals += 1
 
@@ -512,6 +545,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
         labels, n_clusters = _finalize(labels_sorted, segs.order, n)
         core_mask = jnp.zeros(n, bool).at[segs.order].set(core)
         sp.watch(labels, core_mask)
+    _fold_walks(walks)
     res = DBSCANResult(labels=labels, core_mask=core_mask,
                        n_clusters=n_clusters, n_sweeps=n_sweeps,
                        n_traversals=n_traversals, backend=backend)

@@ -3,9 +3,16 @@
   PYTHONPATH=src python -m repro.launch.cluster --data hacc_like -n 20000 \
       --eps 0.03 --minpts 5 --algorithm fdbscan-densebox
 
-``--trace``/``--metrics-json`` record the run's phase spans (plan/build/
-traverse/sweep/border, DESIGN.md §12) and metrics snapshot — the batch
-analogue of the serving loop's observability artifacts.
+``--trace``/``--metrics-json`` record the run's phase spans and metrics
+snapshot (DESIGN.md §12) — the batch analogue of the serving loop's
+observability artifacts. The trace holds the spans plan/build/lbvh/
+dbscan/traverse/sweep/frontier/border/finalize and a ``jax.compile`` span
+for each program lowered or compiled (or loaded from the persistent
+cache), naming the function and the span it ran in; its
+``otherData.epoch_unix_ns`` is the tracer's epoch on the wall clock, so
+it can be laid over a profiler capture of the same run. The snapshot
+counts runs (``dbscan_runs_total``) and the walks' distance evaluations
+(``traversal_evals_total``); installing it changes no compiled program.
 """
 from __future__ import annotations
 

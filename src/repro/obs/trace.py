@@ -21,10 +21,23 @@ mode: watches are recorded as ``"none"`` and nothing ever blocks.
 
 ``jax.profiler`` shim (the paxml ``cuda_profile_hook`` shape): with
 ``annotate=True`` every span also enters a
-``jax.profiler.TraceAnnotation``, so when a JAX profiler capture is
-active (e.g. under :func:`profiler_session`) the same phase names appear
-on the profiler timeline; without an active capture the annotation is a
-cheap no-op.
+``jax.profiler.TraceAnnotation`` under the same name, carrying the
+span's scalar attributes as the event's stats, so when a JAX profiler
+capture is active (e.g. under :func:`profiler_session`) the same phase
+names appear on the profiler timeline, on the device trace's clock;
+without an active capture the annotation is a cheap no-op. Attributes
+added later with :meth:`Span.set` reach the exported trace only.
+
+Compile spans: while a tracer is installed, every lowering and every
+backend compile (or load from the persistent compilation cache) JAX
+reports is recorded as a complete event named :data:`COMPILE_SPAN`, with
+the jitted function's name (``fun``), the ``stage`` and the innermost
+open span of the compiling thread (``span``), so a recompile is placed
+in the phase that paid for it.
+
+The exported document records the tracer's epoch on the wall clock
+(``otherData.epoch_unix_ns``): an event's wall-clock start is that plus
+its ``ts``, which lays a trace over a profiler capture of the same run.
 
 Disabled-by-default: with no tracer installed, :func:`span` returns a
 shared no-op context manager — one module-global load per call site.
@@ -43,6 +56,17 @@ TRACE_SCHEMA = "repro.obs.trace/v1"
 # Event-buffer cap: tracing is for runs a human inspects, not a flight
 # recorder — past the cap new events are dropped and counted.
 MAX_EVENTS = 200_000
+
+#: name of the events that time JAX's lowering and compilation
+COMPILE_SPAN = "jax.compile"
+# JAX's monitoring events behind the compile spans, by stage; "compile"
+# includes a load from the persistent compilation cache. Jaxpr tracing
+# is left out: an eager call traces hundreds of operations, each well
+# under a millisecond.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
 
 
 class Span:
@@ -63,10 +87,14 @@ class Span:
         if self._tracer.sync:
             self._watched.extend(values)
 
+    def set(self, **attrs) -> None:
+        """Add attributes known only after the span opened."""
+        self.attrs.update(attrs)
+
     def __enter__(self) -> "Span":
         self._tracer._stack().append(self)
         if self._tracer.annotate:
-            self._ann = _enter_annotation(self.name)
+            self._ann = _enter_annotation(self.name, self.attrs)
         self._t0 = time.perf_counter()
         return self
 
@@ -92,6 +120,9 @@ class _NoopSpan:
     __slots__ = ()
 
     def watch(self, *values) -> None:
+        pass
+
+    def set(self, **attrs) -> None:
         pass
 
     def __enter__(self) -> "_NoopSpan":
@@ -121,6 +152,7 @@ class Tracer:
         self.events: list[dict] = []
         self.n_dropped = 0
         self._epoch = time.perf_counter()
+        self._epoch_unix_ns = time.time_ns()
         self._lock = threading.Lock()
         self._local = threading.local()
 
@@ -155,7 +187,8 @@ class Tracer:
             "displayTimeUnit": "ms",
             "otherData": {"schema": TRACE_SCHEMA,
                           "sync": "blocked" if self.sync else "none",
-                          "dropped_events": self.n_dropped},
+                          "dropped_events": self.n_dropped,
+                          "epoch_unix_ns": self._epoch_unix_ns},
         }
 
     def export(self, path: str) -> dict:
@@ -179,15 +212,23 @@ def _jsonable(v):
 # ---------------------------------------------------------------------- #
 
 _active: Tracer | None = None
+_listening = False
 
 
 def install(tracer: Tracer | None = None, *, sync: bool = True,
             annotate: bool = True) -> Tracer:
     """Install ``tracer`` (or a fresh ``Tracer(sync=, annotate=)``) as the
-    process-wide span collector and return it."""
-    global _active
+    process-wide span collector and return it; from then on it also
+    records :data:`COMPILE_SPAN` events."""
+    global _active, _listening
     _active = tracer if tracer is not None else Tracer(sync=sync,
                                                        annotate=annotate)
+    if not _listening:
+        # a monitoring listener cannot be removed: register it once per
+        # process; it returns at once while no tracer is installed
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
     return _active
 
 
@@ -222,15 +263,35 @@ def watch(*values) -> None:
         stack[-1].watch(*values)
 
 
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    """JAX monitoring listener: record a lowering or compile that just
+    ended as a :data:`COMPILE_SPAN` event on the installed tracer."""
+    t = _active
+    if t is None:
+        return
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    now = time.perf_counter()
+    stack = t._stack()
+    # a compile under way when the tracer was made starts at its epoch
+    t._record(COMPILE_SPAN, max(now - duration, t._epoch), now,
+              {"fun": kwargs.get("fun_name"), "stage": stage,
+               "span": stack[-1].name if stack else None}, False)
+
+
 # ---------------------------------------------------------------------- #
 # jax.profiler shim                                                      #
 # ---------------------------------------------------------------------- #
 
-def _enter_annotation(name: str):
-    """Enter a ``jax.profiler.TraceAnnotation(name)``; the annotation is
-    visible only while a profiler capture is active."""
+def _enter_annotation(name: str, attrs: dict):
+    """Enter a ``jax.profiler.TraceAnnotation(name)`` carrying the
+    scalar ``attrs`` as its stats; the annotation is visible only while
+    a profiler capture is active."""
     from jax import profiler
-    ann = profiler.TraceAnnotation(name)
+    ann = profiler.TraceAnnotation(
+        name, **{k: v for k, v in attrs.items()
+                 if isinstance(v, (str, int, float))})
     ann.__enter__()
     return ann
 

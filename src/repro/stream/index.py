@@ -764,9 +764,11 @@ class StreamingDBSCAN:
         if n >= 2 and tree is not None:
             core_s, labels0, vals0, absorbed, tr = fdbscan._fused_first_pass(
                 tree, segs, self.eps, self.min_pts)
+            walks = fdbscan._walk_log()
             core_labels, _, _ = fdbscan._sweep_to_fixpoint(
                 tree, segs, self.eps, core_s, labels0,
-                fused_init=(vals0, absorbed))
+                fused_init=(vals0, absorbed), walks=walks)
+            fdbscan._fold_walks(walks)
             counts_s = np.minimum(np.asarray(tr.hits) + 1,
                                   self.min_pts).astype(np.int32)
             core_np = np.asarray(core_s)

@@ -299,3 +299,195 @@ def test_serve_emits_valid_artifacts(tmp_path):
     assert stats["insert_p50_ms"] > 0
     # collectors installed by serve.main must not leak into the session
     assert metrics.active() is None and trace.active() is None
+
+
+# --------------------------------------------------------------------- #
+# span attributes set late, the wall-clock epoch, compile spans         #
+# --------------------------------------------------------------------- #
+
+def test_span_set_on_live_and_noop_span():
+    tr = trace.Tracer(sync=False, annotate=False)
+    with tr.span("frontier", stage="next") as sp:
+        sp.set(n_changed=5, lanes=64)
+    assert tr.events[0]["args"] == {"stage": "next", "n_changed": 5,
+                                    "lanes": 64, "sync": "none"}
+    assert trace.active() is None
+    with trace.span("frontier") as sp:
+        sp.set(n_changed=1)                   # the shared no-op
+
+
+def test_export_records_the_wall_clock_epoch():
+    import time
+    before = time.time_ns()
+    tr = trace.Tracer(sync=False, annotate=False)
+    after = time.time_ns()
+    with tr.span("s"):
+        pass
+    doc = tr.to_dict()
+    trace.validate_chrome_trace(doc)
+    assert before <= doc["otherData"]["epoch_unix_ns"] <= after
+    # an event's wall-clock start: the epoch plus its ts (microseconds)
+    start = doc["otherData"]["epoch_unix_ns"] + doc["traceEvents"][0][
+        "ts"] * 1e3
+    assert before <= start <= time.time_ns()
+
+
+def test_compile_spans_name_the_function_and_the_enclosing_span():
+    import jax
+    import jax.numpy as jnp
+
+    def fresh_program(x):
+        return jnp.cumsum(x * 3) - 1
+
+    x = jnp.arange(7.0)
+    tr = trace.install(sync=False, annotate=False)
+    try:
+        with trace.span("plan"):
+            jax.jit(fresh_program)(x).block_until_ready()
+    finally:
+        trace.uninstall()
+    compiles = [e for e in tr.events if e["name"] == trace.COMPILE_SPAN
+                and "fresh_program" in e["args"]["fun"]]
+    assert sorted(e["args"]["stage"] for e in compiles) == ["compile",
+                                                            "lower"]
+    for e in compiles:
+        assert e["args"]["span"] == "plan"
+        assert e["dur"] >= 0
+    plan = next(e for e in tr.events if e["name"] == "plan")
+    assert all(plan["ts"] <= e["ts"] and e["ts"] + e["dur"]
+               <= plan["ts"] + plan["dur"] + 1 for e in compiles)
+    trace.validate_chrome_trace(tr.to_dict())
+
+    # no tracer installed: the listener records nothing anywhere
+    n_events = len(tr.events)
+
+    def another_program(x):
+        return jnp.cumprod(x) + 2
+    jax.jit(another_program)(x).block_until_ready()
+    assert len(tr.events) == n_events
+
+
+def test_annotation_attributes_reach_a_profiler_capture(tmp_path):
+    import glob
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    tr = trace.Tracer(sync=False, annotate=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("sweep", i=3, lanes=128, engine="reference"):
+            jnp.arange(16).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    found = [dict(e.stats) for plane in profile.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == "sweep"]
+    assert found and found[0]["i"] == 3 and found[0]["lanes"] == 128
+    assert found[0]["engine"] == "reference"
+
+
+# --------------------------------------------------------------------- #
+# the batch path's spans, and the registry's observer effect            #
+# --------------------------------------------------------------------- #
+
+def _auto_points():
+    from repro.data import pointclouds
+    # above the tiled cut-off, so auto builds a densebox tree and sweeps
+    return pointclouds.load("portotaxi_like", 1500), 0.02, 5
+
+
+def _inside(inner, outer):
+    return (outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + 1)
+
+
+def test_lbvh_nests_in_plan_and_frontier_in_dbscan():
+    import repro
+    from repro.core import dispatch
+    pts, eps, mp = _auto_points()
+    dispatch.clear_cache()
+    with obs.instrumented(sync=True) as (_, tr):
+        res = repro.dbscan(pts, eps, mp, algorithm="auto")
+    dispatch.clear_cache()
+    assert res.backend == "fdbscan-densebox"
+    by = {}
+    for e in tr.events:
+        by.setdefault(e["name"], []).append(e)
+    (plan,), (dbscan_span,), (lbvh_span,) = (by["plan"], by["dbscan"],
+                                             by["lbvh"])
+    assert _inside(lbvh_span, plan)
+    assert lbvh_span["args"]["sync"] == "blocked"
+    frontier = by["frontier"]
+    assert all(_inside(e, dbscan_span) for e in frontier)
+    setup, *nexts = frontier
+    assert setup["args"]["stage"] == "setup"
+    assert [e["args"]["stage"] for e in nexts] == ["next"] * len(nexts)
+    # one "next" after every sweep; the last finds no change
+    sweeps = by["sweep"]
+    assert len(nexts) == len(sweeps) == res.n_sweeps - 1
+    assert [e["args"]["n_changed"] > 0 for e in nexts] == \
+        [True] * (len(nexts) - 1) + [False]
+    # each sweep walks the lanes the frontier work before it chose
+    lanes = [setup["args"]["lanes"]] + [e["args"]["lanes"] for e in nexts]
+    assert [s["args"]["lanes"] for s in sweeps] == lanes[:-1]
+    assert lanes[-1] == 0
+
+
+def test_metrics_registry_adds_no_compiles():
+    import jax
+    import repro
+    from repro.core import dispatch
+    counts, phase = {}, [None]
+
+    def listen(event, duration, **kwargs):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and phase[0] is not None):
+            counts[phase[0]] = counts.get(phase[0], 0) + 1
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    pts, eps, mp = _auto_points()
+
+    def call():
+        dispatch.clear_cache()
+        return repro.dbscan(pts, eps, mp, algorithm="auto")
+    call()                                    # warm: every shape compiled
+    try:
+        phase[0] = "plain"
+        plain = call()
+        phase[0] = "registry"
+        with obs.instrumented(sync=True) as (reg, _):
+            traced = call()
+    finally:
+        phase[0] = None          # a listener cannot be removed: mute it
+        dispatch.clear_cache()
+    assert counts.get("plain", 0) == counts.get("registry", 0)
+    np.testing.assert_array_equal(np.asarray(plain.labels),
+                                  np.asarray(traced.labels))
+    assert reg.get("traversal_evals_total", phase="sweep",
+                   engine="reference").value > 0
+
+
+def test_evals_counter_matches_the_walks_own_counts():
+    from repro.core import dispatch, fdbscan
+    pts, eps, mp = _auto_points()
+    dispatch.clear_cache()
+    p = dispatch.plan(pts, eps, mp, algorithm="auto")
+    dispatch.clear_cache()
+    _, stats = fdbscan.cluster_from_index(p.segs, p.tree, eps, mp,
+                                          backend=p.backend, with_stats=True)
+    with obs.instrumented(sync=False) as (reg, _):
+        fdbscan.cluster_from_index(p.segs, p.tree, eps, mp,
+                                   backend=p.backend)
+
+    def counted(phase):
+        return reg.get("traversal_evals_total", phase=phase,
+                       engine="reference").value
+    assert counted("first_pass") == stats["first_pass_evals"]
+    assert counted("sweep") == sum(stats["evals_per_sweep"])
+    assert counted("border") >= 0
+    families = {m["name"] for m in reg.snapshot()["metrics"]}
+    assert "traversal_iters_total" not in families
