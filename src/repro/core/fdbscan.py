@@ -175,26 +175,50 @@ def _walk_log() -> list | None:
     return [] if obs_metrics.active() is not None else None
 
 
-def _record_walk(walks: list | None, phase: str, engine: str, tr) -> None:
-    """Keep a walk's per-lane ``evals`` for :func:`_fold_walks`. Keeping
-    a device array reads nothing, so the walk's timing is unperturbed."""
+def _record_walk(walks: list | None, phase: str, engine: str, tr,
+                 span=None) -> None:
+    """Keep a walk's per-lane ``evals`` and ``iters`` and its stage trips
+    for :func:`_fold_walks`; keeping device arrays reads nothing, so the
+    walk's timing is unperturbed. While a tracer is installed, also put
+    the walk's issued and live lane-trips on ``span`` (call this inside
+    it): ``lane_trips`` and ``live_lane_trips``, read from the device."""
     if walks is not None:
-        walks.append((phase, engine, tr.evals))
+        walks.append((phase, engine, tr.evals, tr.iters, tr.stages))
+    if (span is not None and tr.stages is not None
+            and obs_trace.active() is not None):
+        issued, live = _lane_trips(*jax.device_get((tr.stages, tr.iters)))
+        span.set(lane_trips=issued, live_lane_trips=live)
+
+
+def _lane_trips(stages, iters) -> tuple:
+    """(issued, live) lane-trips of a walk, in int64 on the host: each
+    stage's width times its trips, summed, and the lanes' trips summed."""
+    stages = np.asarray(stages, np.int64)
+    return (int(np.sum(stages[:, 0] * stages[:, 1])),
+            int(np.asarray(iters).sum(dtype=np.int64)))
 
 
 def _fold_walks(walks: list | None) -> None:
     """Fold the kept walks' work counters into
-    ``traversal_evals_total{phase=,engine=}`` (DESIGN.md §12): one host
-    transfer of every walk's ``evals`` and a numpy sum, once the caller
-    has synced on its result anyway — no device reduction, no sync per
-    walk, no program of its own."""
+    ``traversal_evals_total{phase=,engine=}`` and
+    ``traversal_lane_trips_total{phase=,engine=,kind=issued|live}``
+    (DESIGN.md §12): one host transfer of every walk's counters and numpy
+    sums, once the caller has synced on its result anyway — no device
+    reduction, no sync per walk, no program of its own. Engines without
+    stages (the Pallas kernel) count no lane-trips."""
     if not walks:
         return
-    host = jax.device_get([evals for _, _, evals in walks])
-    for (phase, engine, _), evals in zip(walks, host):
+    host = jax.device_get([w[2:] for w in walks])
+    for (phase, engine, *_), (evals, iters, stages) in zip(walks, host):
         obs_metrics.inc("traversal_evals_total",
                         float(np.sum(evals, dtype=np.int64)),
                         phase=phase, engine=engine)
+        if stages is None:
+            continue
+        issued, live = _lane_trips(stages, iters)
+        for kind, value in (("issued", issued), ("live", live)):
+            obs_metrics.inc("traversal_lane_trips_total", float(value),
+                            phase=phase, engine=engine, kind=kind)
 
 
 def _gather_minlabel(tree, segs, eps, labels, gather_mask, ids,
@@ -375,7 +399,7 @@ def _sweep_to_fixpoint(tree, segs, eps, core, labels0, *,
             new, changed, changed_flags = _post_sweep(tree, segs, labels,
                                                       core, ids, tr.acc)
             sp.watch(new, changed)
-        _record_walk(walks, "sweep", engine, tr)
+            _record_walk(walks, "sweep", engine, tr, sp)
         sweeps += 1
         if collect_stats:
             stats["frontier_per_sweep"].append(int(jnp.sum(gather_mask)))
@@ -417,11 +441,13 @@ def _main_phase(tree, segs, eps, core, *, frontier: bool = True):
 
 
 def _assign_borders(tree, segs, eps, core, core_labels,
-                    traverse_fn=traversal.traverse, tune=None, walks=None):
+                    traverse_fn=traversal.traverse, tune=None, walks=None,
+                    span=None):
     """Borders take the min adjacent core root; isolated non-core -> noise.
 
     Traverses a compacted non-core query set (usually a small minority),
     pruning subtrees that hold no core point (nothing to gather there).
+    ``walks`` and ``span`` go to :func:`_record_walk`.
     """
     ids = _compact_ids(np.asarray(~core))
     depth_rank = None
@@ -437,7 +463,7 @@ def _assign_borders(tree, segs, eps, core, core_labels,
                                                                   core),
                                     traverse_fn=traverse_fn,
                                     depth_rank=depth_rank)
-    _record_walk(walks, "border", _engine_name(traverse_fn), tr)
+    _record_walk(walks, "border", _engine_name(traverse_fn), tr, span)
     labels = jnp.where(core, core_labels, gathered)
     return jnp.where(labels == INT_MAX, jnp.int32(-1), labels)
 
@@ -517,7 +543,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
             tree, segs, eps, min_pts, traverse_fn=fp_fn,
             depth_rank=fp_rank)
         sp.watch(core, labels0)
-    _record_walk(walks, "first_pass", engine, first)
+        _record_walk(walks, "first_pass", engine, first, sp)
     if tune is not None:
         # The pass's per-query loop-trip counts are the depth oracle for
         # every later reorder="depth" traversal over this plan (free: the
@@ -537,7 +563,7 @@ def cluster_from_index(segs: grid.Segments, tree, eps: float, min_pts: int,
             labels_sorted = _assign_borders(tree, segs, eps, core,
                                             core_labels,
                                             traverse_fn=traverse_fn,
-                                            tune=tune, walks=walks)
+                                            tune=tune, walks=walks, span=sp)
             sp.watch(labels_sorted)
         n_traversals += 1
 

@@ -202,7 +202,12 @@ class Trace(NamedTuple):
     carry: the callback's accumulator pytree, one entry per lane.
     evals: member distance evaluations — the paper's work metric.
     iters: while_loop trips taken (after unrolling); the loop-overhead
-           metric that ``unroll`` amortizes.
+           metric that ``unroll`` amortizes. Their sum is the walk's
+           live lane-trips.
+    stages: (S, 2) int32, one row per stage of the lane schedule
+           (:func:`stage_widths`): the stage's lane width and its
+           while_loop trips. Width times trips, summed, is the walk's
+           issued lane-trips. None from engines without stages.
 
     ``acc``/``hits`` forward into an :class:`AccHits` carry so the DBSCAN
     epilogues read like the pre-redesign engine's outputs.
@@ -210,6 +215,7 @@ class Trace(NamedTuple):
     carry: Any
     evals: jax.Array
     iters: jax.Array
+    stages: Any = None
 
     @property
     def acc(self):
@@ -694,6 +700,10 @@ def traverse_impl(tree: Tree, segs: Segments, predicates, callback,
         (the split first main sweep, DESIGN.md §4).
     unroll: work units per while-loop trip (``None``:
         :func:`default_unroll`).
+
+    Lanes run under the schedule of :func:`stage_widths` (a pool with
+    refill, then a tail by halves; DESIGN.md §4), which moves lanes but
+    never changes a per-lane result.
     """
     if unroll is None:
         unroll = default_unroll()
@@ -704,82 +714,185 @@ def traverse_impl(tree: Tree, segs: Segments, predicates, callback,
      is_nearest) = lane_arrays(segs, predicates, use_range_mask)
     if carry is None:
         carry = callback.init_carry(query_ids, external, segs)
+    carry0 = carry
     if wide_lanes is None:
         wide_lanes = jnp.zeros_like(query_ids, dtype=bool)
 
-    lanes = (q_arr, self_arr, dense_arr, rank_arr, wide_lanes)
+    lanes = lanes0 = (q_arr, self_arr, dense_arr, rank_arr, wide_lanes)
 
-    def lane_step(q, q_self, q_dense, q_rank, lane_wide):
-        ctx = QueryCtx(self_id=q_self, dense=q_dense, rank=q_rank,
-                       wide=lane_wide)
-        return make_step(tree, segs, callback, q=q, ctx=ctx,
-                         lane_wide=lane_wide, r2=r2, is_nearest=is_nearest,
-                         node_mask=node_mask, node_mask_wide=node_mask_wide,
-                         use_range_mask=use_range_mask)
+    n_lanes = query_ids.shape[0]
+    zeros = jnp.zeros(n_lanes, jnp.int32)
+    # padding lanes (ids -1) start finished
+    state = (jnp.where(query_ids >= 0, root, jnp.int32(-1)),
+             jnp.full(n_lanes, -1, jnp.int32), carry, zeros, zeros)
+    walk = (tree, segs, callback, r2, node_mask, node_mask_wide)
+    flags = dict(is_nearest=is_nearest, use_range_mask=use_range_mask)
 
     def lane_live(state, *lane):
         node, ptr, carry, evals, iters = state
-        return lane_step(*lane)[1](node, carry)
+        return _lane_step(*walk, lane, **flags)[1](node, carry)
 
+    live_all = jax.vmap(lane_live)
+
+    def trip_all(state, *lanes):
+        return _trip_all(*walk, state, lanes, unroll=unroll, **flags)
+
+    def walk_while(cond_live, state, lanes):
+        """Trip every lane of a batch until at most ``cond_live`` are
+        live; returns the state and the number of trips."""
+        def cond(s):
+            return jnp.sum(live_all(s[0], *lanes)) > cond_live
+        return lax.while_loop(
+            cond, lambda s: (trip_all(s[0], *lanes), s[1] + 1),
+            (state, jnp.int32(0)))
+
+    out = state[2:]           # (carry, evals, iters): what the walk returns
+    pos = jnp.arange(n_lanes, dtype=jnp.int32)
+    widths = stage_widths(n_lanes)
+    trips = []
+    if n_lanes > POOL_LANES:
+        width = widths[0]
+        nxt = widths[1] if len(widths) > 1 else 0
+        # lane positions waiting to walk, in input order (Morton order for
+        # resident queries); padding lanes never wait
+        waiting = query_ids >= 0
+        n_waiting = jnp.sum(waiting, dtype=jnp.int32)
+        queue = jnp.nonzero(waiting, size=n_lanes,
+                            fill_value=n_lanes)[0].astype(jnp.int32)
+
+        def refill(r):
+            """Walk until at most 3/4 of the pool is live (once no lane
+            waits: as many as the next stage holds), write the finished
+            lanes back, and give their slots the next waiting lanes: the
+            root node, no member pointer, zeroed counters, and the lane's
+            rows of the initial carry and lane arrays."""
+            st, lp, ps, out, head, _, t = r
+            st, dt = walk_while(
+                jnp.where(head < n_waiting, 3 * width // 4, nxt), st, lp)
+            dead = ~live_all(st, *lp)
+            out = jax.tree.map(
+                lambda o, x: o.at[jnp.where(dead, ps, n_lanes)].set(
+                    x, mode="drop"), out, st[2:])
+            k = head + jnp.cumsum(dead, dtype=jnp.int32) - 1
+            fill = dead & (k < n_waiting)
+            src = jnp.where(fill, queue[jnp.minimum(k, n_lanes - 1)], 0)
+            node, ptr, c, ev, it = st
+            st = (jnp.where(fill, root, node), jnp.where(fill, -1, ptr),
+                  jax.tree.map(lambda x, x0: _rows_where(fill, x0[src], x),
+                               c, carry0),
+                  jnp.where(fill, 0, ev), jnp.where(fill, 0, it))
+            lp = jax.tree.map(lambda x, x0: _rows_where(fill, x0[src], x),
+                              lp, lanes0)
+            n_live = jnp.sum(~dead | fill, dtype=jnp.int32)
+            return (st, lp, jnp.where(fill, src, ps), out,
+                    head + jnp.sum(fill, dtype=jnp.int32), n_live, t + dt)
+
+        # the pool starts with every slot finished and writing nowhere,
+        # so the first refill fills it; it ends once no lane waits and
+        # the next stage holds its live lanes
+        pool, pool_lanes = jax.tree.map(lambda x: x[:width], (state, lanes))
+        pool = (jnp.full(width, -1, jnp.int32),) + pool[1:]
+        state, lanes, pos, out, _, _, t = lax.while_loop(
+            lambda r: (r[4] < n_waiting) | (r[5] > nxt), refill,
+            (pool, pool_lanes, jnp.full(width, n_lanes, jnp.int32), out,
+             jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+        trips.append(t)
+        if nxt:
+            state, lanes, pos = _compact(live_all(state, *lanes), nxt,
+                                         n_lanes, (state, lanes, pos))
+        widths_tail = widths[1:]
+    else:
+        widths_tail = widths
+    for k, width in enumerate(widths_tail):
+        nxt = widths_tail[k + 1] if k + 1 < len(widths_tail) else 0
+        state, t = walk_while(nxt, state, lanes)
+        trips.append(t)
+        # write this stage's lanes back (finished ones are final; the
+        # still-live ones are overwritten by a later stage)
+        out = jax.tree.map(lambda o, x: o.at[pos].set(x, mode="drop"),
+                           out, state[2:])
+        if nxt:
+            state, lanes, pos = _compact(live_all(state, *lanes), nxt,
+                                         n_lanes, (state, lanes, pos))
+    carry, evals, iters = out
+    stages = jnp.stack([jnp.asarray(widths, jnp.int32), jnp.stack(trips)],
+                       axis=1)
+    return Trace(carry=carry, evals=evals, iters=iters, stages=stages)
+
+
+def _lane_step(tree, segs, callback, r2, node_mask, node_mask_wide, lane, *,
+               is_nearest, use_range_mask):
+    """:func:`make_step` for one lane, from its row of the lane arrays
+    (query point, self id, dense flag, segment rank, wide flag)."""
+    q, q_self, q_dense, q_rank, lane_wide = lane
+    ctx = QueryCtx(self_id=q_self, dense=q_dense, rank=q_rank,
+                   wide=lane_wide)
+    return make_step(tree, segs, callback, q=q, ctx=ctx, lane_wide=lane_wide,
+                     r2=r2, is_nearest=is_nearest, node_mask=node_mask,
+                     node_mask_wide=node_mask_wide,
+                     use_range_mask=use_range_mask)
+
+
+@partial(jax.jit, inline=True,
+         static_argnames=("is_nearest", "use_range_mask", "unroll"))
+def _trip_all(tree, segs, callback, r2, node_mask, node_mask_wide, state,
+              lanes, *, is_nearest, use_range_mask, unroll):
+    """One while-loop trip of every lane of a batch: ``unroll``
+    dead-guarded steps per lane (a finished lane stays frozen, as under a
+    per-lane loop). Jitted and inlined, so a process traces it once per
+    batch width and visitor structure, for every walk program and stage
+    that share them: a warm process traces each walk program before its
+    cache load."""
     def lane_trip(state, *lane):
-        """One while-loop trip of one lane: ``unroll`` dead-guarded steps
-        (a finished lane stays frozen, as under a per-lane loop)."""
         node, ptr, carry, evals, iters = state
-        step, live_of = lane_step(*lane)
+        step, live_of = _lane_step(
+            tree, segs, callback, r2, node_mask, node_mask_wide, lane,
+            is_nearest=is_nearest, use_range_mask=use_range_mask)
         live = live_of(node, carry)
         inner = (node, ptr, carry, evals)
         for _ in range(unroll):
             inner = step(inner)
         return (*inner, iters + jnp.where(live, 1, 0))
 
-    live_all = jax.vmap(lane_live)
-    trip_all = jax.vmap(lane_trip)
-    n_lanes = query_ids.shape[0]
-    zeros = jnp.zeros(n_lanes, jnp.int32)
-    state = (jnp.where(query_ids >= 0, root, jnp.int32(-1)),
-             jnp.full(n_lanes, -1, jnp.int32), carry, zeros, zeros)
-    out = state
-    pos = jnp.arange(n_lanes, dtype=jnp.int32)
-    widths = compact_widths(n_lanes)
-    for k, width in enumerate(widths):
-        nxt = widths[k + 1] if k + 1 < len(widths) else 0
-
-        def cond(st, lanes=lanes, nxt=nxt):
-            return jnp.sum(live_all(st, *lanes)) > nxt
-
-        state = lax.while_loop(
-            cond, lambda st, lanes=lanes: trip_all(st, *lanes), state)
-        # write this stage's lanes back (finished ones are final; the
-        # still-live ones are overwritten by a later stage)
-        out = jax.tree.map(lambda o, x: o.at[pos].set(x, mode="drop"),
-                           out, state)
-        if nxt:
-            # at most nxt lanes are live: move them, in order, into a
-            # batch of width nxt; spare slots are dead and write nowhere
-            live = live_all(state, *lanes)
-            idx = jnp.nonzero(live, size=nxt, fill_value=0)[0]
-            spare = jnp.arange(nxt) >= jnp.sum(live)
-            state, lanes, pos = jax.tree.map(lambda x: x[idx],
-                                             (state, lanes, pos))
-            state = (jnp.where(spare, -1, state[0]),) + state[1:]
-            pos = jnp.where(spare, n_lanes, pos)
-    node, ptr, carry, evals, iters = out
-    return Trace(carry=carry, evals=evals, iters=iters)
+    return jax.vmap(lane_trip)(state, *lanes)
 
 
-# Lane compaction (DESIGN.md §4): a batched walk costs every lane one trip
-# for as long as its slowest lane walks, so once at most a quarter of the
-# lanes are live they move into a batch a quarter as wide, down to this
-# width. Exact: lanes never interact.
-COMPACT_FACTOR = 4
-COMPACT_MIN_LANES = 2048
+def _compact(live, width: int, n_lanes: int, batch):
+    """Move the (at most ``width``) live lanes of ``batch`` = (state,
+    lanes, pos), in order, into a batch of ``width``; spare slots are
+    finished and write nowhere (position ``n_lanes``)."""
+    idx = jnp.nonzero(live, size=width, fill_value=0)[0]
+    spare = jnp.arange(width) >= jnp.sum(live)
+    state, lanes, pos = jax.tree.map(lambda x: x[idx], batch)
+    state = (jnp.where(spare, -1, state[0]),) + state[1:]
+    return state, lanes, jnp.where(spare, n_lanes, pos)
 
 
-def compact_widths(n_lanes: int) -> list:
-    """Batch widths a walk of ``n_lanes`` lanes compacts through."""
-    widths = [n_lanes]
-    while widths[-1] // COMPACT_FACTOR >= COMPACT_MIN_LANES:
-        widths.append(widths[-1] // COMPACT_FACTOR)
+def _rows_where(mask, a, b):
+    """``jnp.where`` with a per-row ``mask`` over arrays of any rank."""
+    return jnp.where(mask.reshape(mask.shape + (1,) * (a.ndim - 1)), a, b)
+
+
+# Lane scheduling (DESIGN.md §4): a batched walk costs every lane one trip
+# for as long as its slowest lane walks. A walk wider than POOL_LANES runs
+# at that width: once at most three quarters of its lanes are live, the
+# finished ones are written back and their slots take the next waiting
+# queries. Once none wait, the live lanes move into a batch half as wide
+# whenever at most half are live, down to TAIL_MIN_LANES. Exact: lanes
+# never interact.
+POOL_LANES = 8192
+TAIL_MIN_LANES = 512
+
+
+def stage_widths(n_lanes: int) -> list:
+    """Lane widths of the stages a walk of ``n_lanes`` lanes runs
+    through: the pool first, for a walk wider than ``POOL_LANES``, then
+    the tail's halvings."""
+    width = min(n_lanes, POOL_LANES)
+    widths = [width]
+    while width // 2 >= TAIL_MIN_LANES:
+        width //= 2
+        widths.append(width)
     return widths
 
 

@@ -491,3 +491,55 @@ def test_evals_counter_matches_the_walks_own_counts():
     assert counted("border") >= 0
     families = {m["name"] for m in reg.snapshot()["metrics"]}
     assert "traversal_iters_total" not in families
+
+
+def test_lane_trips_reach_the_spans_and_the_registry():
+    """Each walk's issued and live lane-trips land on its span and in
+    ``traversal_lane_trips_total``; counting them compiles nothing."""
+    import jax
+    from repro.core import dispatch, fdbscan
+    counts, phase = {}, [None]
+
+    def listen(event, duration, **kwargs):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and phase[0] is not None):
+            counts[phase[0]] = counts.get(phase[0], 0) + 1
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    pts, eps, mp = _auto_points()
+    dispatch.clear_cache()
+    p = dispatch.plan(pts, eps, mp, algorithm="auto")
+    dispatch.clear_cache()
+
+    def call(**kw):
+        return fdbscan.cluster_from_index(p.segs, p.tree, eps, mp,
+                                          backend=p.backend, **kw)
+    _, stats = call(with_stats=True)          # warm: every shape compiled
+    try:
+        phase[0] = "plain"
+        call()
+        phase[0] = "counted"
+        with obs.instrumented(sync=True) as (reg, tracer):
+            call()
+    finally:
+        phase[0] = None          # a listener cannot be removed: mute it
+    assert counts.get("plain", 0) == counts.get("counted", 0)
+
+    def counted(phase, kind):
+        return reg.get("traversal_lane_trips_total", phase=phase,
+                       engine="reference", kind=kind).value
+    assert counted("first_pass", "live") == stats["first_pass_iters"]
+    assert counted("sweep", "live") == sum(stats["iters_per_sweep"])
+    for ph in ("first_pass", "sweep", "border"):
+        assert counted(ph, "issued") >= counted(ph, "live") > 0
+    walks = [e for e in tracer.events
+             if e["name"] in ("traverse", "sweep", "border")]
+    assert {e["name"] for e in walks} == {"traverse", "sweep", "border"}
+    for e in walks:
+        assert e["args"]["lane_trips"] >= e["args"]["live_lane_trips"] > 0
+    by_phase = {"traverse": "first_pass", "sweep": "sweep",
+                "border": "border"}
+    for name, ph in by_phase.items():
+        for kind, key in (("issued", "lane_trips"),
+                          ("live", "live_lane_trips")):
+            assert sum(e["args"][key] for e in walks
+                       if e["name"] == name) == counted(ph, kind)

@@ -194,12 +194,20 @@ def test_minpts2_uses_fused_pass():
     assert res.n_traversals == res.n_sweeps + 1
 
 
+def _schedule(monkeypatch, pool, floor):
+    """Set the walk's lane schedule: pool width and tail floor."""
+    monkeypatch.setattr(traversal, "POOL_LANES", pool)
+    monkeypatch.setattr(traversal, "TAIL_MIN_LANES", floor)
+
+
 @pytest.mark.parametrize("kind", ["count", "minlabel", "count_minlabel",
-                                  "nearest", "frontier", "external"])
+                                  "nearest", "frontier", "external",
+                                  "split", "chained"])
 def test_lane_compaction_is_exact(kind, monkeypatch):
-    # Compacting the live lanes into narrower batches mid-walk moves lanes
-    # but never changes one: every per-lane output matches the walk that
-    # keeps all lanes at full width, work counters included.
+    # Refilling a small pool from the waiting queries and halving the
+    # tail moves lanes but never changes one: every per-lane output
+    # matches the walk that keeps all lanes in one full-width batch, work
+    # counters included.
     pts = separated_points(300, 2, eps=0.1, seed=12)
     segs, tree = _index(pts, "fdbscan-densebox", eps=0.1, mp=4)
     n = segs.n_points
@@ -207,25 +215,76 @@ def test_lane_compaction_is_exact(kind, monkeypatch):
     pred = traversal.intersects(sphere)
     cb = _visitor(kind if kind in ("count", "count_minlabel")
                   else "minlabel", n, cap=traversal.INT_MAX)
+    kw = {}
+    # frontier ids: live lanes with -1 padding interspersed
+    frontier_ids = jnp.asarray(
+        np.where(np.arange(n) % 3 == 0, np.arange(n), -1), jnp.int32)
     if kind == "nearest":
         cb, pred = traversal.KNNVisitor(4), traversal.nearest(4)
     elif kind == "frontier":
-        ids = np.where(np.arange(n) % 3 == 0, np.arange(n), -1)
-        pred = traversal.intersects(sphere, ids=jnp.asarray(ids, jnp.int32))
+        pred = traversal.intersects(sphere, ids=frontier_ids)
     elif kind == "external":
         pred = traversal.intersects(sphere, pts=segs.pts[::-1] + 0.01)
+    elif kind == "split":
+        # the split first sweep: per-lane narrow/wide node and gather masks
+        rng = np.random.default_rng(5)
+        narrow = jnp.asarray(rng.random(n) < 0.3)
+        cb = traversal.MinLabelVisitor(jnp.arange(n, dtype=jnp.int32),
+                                       narrow, mask_wide=jnp.ones(n, bool))
+        pred = traversal.intersects(sphere, ids=frontier_ids)
+        kw = dict(node_mask=fdbscan._frontier_node_mask(tree, segs, narrow),
+                  node_mask_wide=jnp.ones(2 * segs.n_segments - 1, bool),
+                  wide_lanes=jnp.asarray(rng.random(n) < 0.5))
+    elif kind == "chained":
+        # an external batch whose carry comes from an earlier walk
+        pred = traversal.intersects(sphere, pts=segs.pts[::-1] + 0.01)
+        cb = traversal.MinLabelVisitor(jnp.arange(n, dtype=jnp.int32)[::-1],
+                                       jnp.ones(n, bool))
+        _schedule(monkeypatch, 10**9, 10**9)
+        kw = dict(carry=traversal.traverse_impl(
+            tree, segs, pred, _visitor("minlabel", n)).carry)
 
-    def walk(min_lanes):
-        monkeypatch.setattr(traversal, "COMPACT_MIN_LANES", min_lanes)
-        return traversal.traverse_impl(tree, segs, pred, cb)
+    def walk(pool, floor):
+        _schedule(monkeypatch, pool, floor)
+        return traversal.traverse_impl(tree, segs, pred, cb, **kw)
 
-    assert len(traversal.compact_widths(n)) == 1
-    full = walk(traversal.COMPACT_MIN_LANES)
-    compacted = walk(8)
-    assert traversal.compact_widths(n) == [n, n // 4, n // 16]
-    for a, b in zip(jax.tree.leaves(full), jax.tree.leaves(compacted)):
+    full = walk(10**9, 10**9)
+    assert traversal.stage_widths(n) == [n]
+    pooled = walk(16, 4)
+    assert traversal.stage_widths(n) == [16, 8, 4]
+    stages = np.asarray(pooled.stages)
+    assert stages[:, 0].tolist() == [16, 8, 4]
+    assert stages[0, 1] > 0 and stages[-1, 1] > 0   # refills and halvings
+    assert np.asarray(full.stages).tolist() == [
+        [n, int(np.asarray(full.iters).max())]]
+    for a, b in zip(jax.tree.leaves(full[:3]), jax.tree.leaves(pooled[:3])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert int(full.evals.sum()) > 0
+
+
+def test_stage_widths_follow_the_schedule():
+    assert traversal.stage_widths(300) == [300]
+    assert traversal.stage_widths(8192) == [8192, 4096, 2048, 1024, 512]
+    assert traversal.stage_widths(65536) == [8192, 4096, 2048, 1024, 512]
+    assert traversal.stage_widths(1500) == [1500, 750]
+
+
+def test_lane_trips_count_the_schedule(monkeypatch):
+    # issued lane-trips (each stage's width times its trips) bound the
+    # live ones (the lanes' own trips), pinned on one fixed input
+    pts = separated_points(300, 2, eps=0.1, seed=12)
+    segs, tree = _index(pts, "fdbscan-densebox", eps=0.1, mp=4)
+    pred = traversal.intersects(traversal.sphere(0.1))
+    cb = _visitor("minlabel", segs.n_points)
+    counts = {}
+    for pool, floor in [(10**9, 10**9), (16, 4)]:
+        _schedule(monkeypatch, pool, floor)
+        tr = traversal.traverse_impl(tree, segs, pred, cb, unroll=1)
+        issued, live = fdbscan._lane_trips(tr.stages, tr.iters)
+        assert live == int(np.asarray(tr.iters).sum())
+        assert issued >= live
+        counts[pool] = (issued, live)
+    assert counts == {10**9: (27000, 14932), 16: (16568, 14932)}
 
 
 @pytest.mark.parametrize("algo", ["fdbscan", "fdbscan-densebox"])
@@ -233,7 +292,7 @@ def test_lane_compaction_keeps_labels(algo, monkeypatch):
     pts = separated_points(400, 2, eps=0.06, seed=13)
     segs, tree = _index(pts, algo, eps=0.06, mp=5)
     ref = fdbscan.cluster_from_index(segs, tree, 0.06, 5)
-    monkeypatch.setattr(traversal, "COMPACT_MIN_LANES", 8)
+    _schedule(monkeypatch, 16, 8)
     jax.clear_caches()
     got = fdbscan.cluster_from_index(segs, tree, 0.06, 5)
     jax.clear_caches()
